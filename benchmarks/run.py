@@ -85,6 +85,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import paper_figures
+    from repro.utils.jax_cache import use_compile_cache
+
+    use_compile_cache()
 
     wanted = set(args.only.split(",")) if args.only else None
     all_rows = {}

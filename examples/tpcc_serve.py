@@ -32,6 +32,7 @@ from repro.txn.latency import DelayModel, simulate
 from repro.txn.tpcc import (TPCCScale, check_consistency, init_state,
                             tpcc_state_specs)
 from repro.txn.twopc import TwoPCEngine, run_closed_loop_2pc
+from repro.utils.jax_cache import use_compile_cache
 
 
 def chaos_demo(args) -> None:
@@ -116,6 +117,7 @@ def main() -> None:
                          "lattice + phase spans + coordination ledger) to "
                          "PATH after the instrumented full-mix run")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.chaos:
         chaos_demo(args)
@@ -156,7 +158,8 @@ def main() -> None:
         n_batches=args.batches, remote_frac=args.remote_frac, merge_every=8)
     print(f"fused:    committed {stats.committed} New-Order txns in "
           f"{stats.wall_seconds:.2f}s -> {stats.throughput:,.0f} txn/s "
-          f"(CPU, {engine.n_shards} shard(s))")
+          f"({jax.devices()[0].platform} {jax.devices()[0].device_kind}, "
+          f"{engine.n_shards} shard(s))")
     sd = engine.shard_state(init_state(scale))
     sd, dstats = run_closed_loop(
         engine, sd, batch_per_shard=args.batch_per_shard,

@@ -19,26 +19,33 @@ fits supply ("Keeping CALM": monotone => coordination- and order-free):
 * **Level 2 — this kernel**: only the *residual* transactions (those with at
   least one line on an oversubscribed cell) still need FCFS order. The
   kernel copies ``avail`` into VMEM once, then walks the residual
-  transactions with a dynamic trip count — per line, one in-VMEM load/store
-  pair and a running tentative reservation (subtract, test ``>= 0``, roll
-  back on abort) replaces both the per-step HBM round-trip and the
-  ``[L, L]`` tril matrix of the scan baseline.
+  transactions with a dynamic trip count — per line, one in-VMEM row
+  read-modify-write and a running tentative reservation (subtract, test
+  ``>= 0``, roll back on abort) replaces both the per-step HBM round-trip
+  and the ``[L, L]`` tril matrix of the scan baseline.
 
 At TPC-C skew the residual set is the oversubscribed handful, so the
 sequential depth collapses from B to ~contended-transaction count, and the
 whole batch costs one avail copy instead of B gather/scatter round trips.
 
-VMEM budget: ``avail`` is ``[A]`` int32 with A = K + W_local * I + 1 (hot
-cells ++ local cold stock ++ remote sentinel). At TPC-C spec scale on the
-production mesh (K = 512k hot cells, 2 local warehouses x 100k items) that
-is ~2.9 MB — comfortably inside the ~16 MB/core VMEM (asserted by the
-dry-run's ``escrow_admission`` cell).
+Layout (what Mosaic, the chip's compiler, accepts): ``avail`` rides in VMEM
+as ``[rows, 128]`` int32 (:func:`to_lanes`), so reaching cell ``s`` is one
+tile-aligned row (``s // 128``) masked to lane ``s % 128``; a dynamic
+single-element slice of a 1-D VMEM array is not tile-legal. The
+per-transaction and per-line scalars (residual order, slots, quantities,
+int32 line masks) and the verdicts live in SMEM. ``avail`` is ``[A]`` with
+A = K + W_local * I + 1 (hot cells ++ local cold stock ++ remote sentinel):
+about 4 MB at TPC-C spec scale with 10 local warehouses.
+tests/test_tpu_compile.py compiles the kernel for a v5e chip at that size.
 
-On CPU (tests, CI, this container) the kernel runs in ``interpret`` mode,
-bit-exact against the ``kernels/ref.py`` oracle, like ``ramp_read``.
+On CPU (tests, CI) the kernel runs in ``interpret`` mode, bit-exact against
+the ``kernels/ref.py`` oracle; the engine's CPU path takes the equivalent
+jnp lowering :func:`residual_fcfs` instead (kernels/ops.py).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -130,42 +137,102 @@ def residual_fcfs(avail0: Array, slot: Array, qty: Array, line_valid: Array,
     return committed, avail
 
 
-def _escrow_admit_body(n_res_ref, res_idx_ref, slot_ref, qty_ref, lv_ref,
-                       fast_ref, avail0_ref, committed_ref, avail_ref):
-    """committed <- fast; avail <- avail0; then FCFS over the residual
-    transactions with avail resident in VMEM (avail_ref doubles as the
-    running reservation state)."""
-    committed_ref[...] = fast_ref[...]
-    avail_ref[...] = avail0_ref[...]
-    L = slot_ref.shape[1]
+LANES = 128
+SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+VMEM = pl.BlockSpec(memory_space=pltpu.VMEM)
+HBM = pl.BlockSpec(memory_space=pl.ANY)
+
+
+def flat_i32(x: Array) -> Array:
+    """Any [B] / [B, L] operand as the flat int32 array SMEM takes."""
+    return x.astype(jnp.int32).reshape(-1)
+
+
+def lane_rows(n: int) -> int:
+    """Rows of the ``[rows, 128]`` int32 layout holding ``n`` cells: whole
+    (8, 128) tiles, so every row slice is tile-aligned for Mosaic."""
+    return max(8, pl.cdiv(pl.cdiv(n, LANES), 8) * 8)
+
+
+def to_lanes(x: Array) -> Array:
+    """``[n]`` -> zero-padded ``[lane_rows(n), 128]`` (flat cell s lives at
+    row s // 128, lane s % 128)."""
+    n = x.shape[0]
+    return jnp.pad(x, (0, lane_rows(n) * LANES - n)).reshape(-1, LANES)
+
+
+def lane_add(ref, s, delta):
+    """``ref.flat[s] += delta`` on a ``[rows, 128]`` VMEM ref; returns the
+    cell's new value (int32). One read-modify-write of row ``s // 128``
+    masked to lane ``s % 128``: a dynamic single-row access is tile-legal
+    where a dynamic single-element slice of a 1-D array is not."""
+    row = pl.ds(s // LANES, 1)
+    hit = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) == s % LANES
+    new = ref[row, :] + jnp.where(hit, delta, 0)
+    ref[row, :] = new
+    return jnp.sum(jnp.where(hit, new, 0))
+
+
+def fcfs_walk(n_res_ref, res_idx_ref, slot_ref, qty_ref, lv_ref,
+              committed_ref, avail_ref):
+    """Level 2: FCFS over the residual transactions against the
+    VMEM-resident ``avail_ref`` (shared by the admission kernel and the
+    megastep). Per-transaction scalars come from SMEM (flat ``[B * L]``
+    line arrays, int32 masks); ``committed_ref`` (SMEM) takes each
+    residual transaction's verdict."""
+    L = slot_ref.shape[0] // committed_ref.shape[0]
 
     def txn(i, carry):
         t = res_idx_ref[i]
-        slots = pl.load(slot_ref, (pl.ds(t, 1), slice(None)))[0]
-        qtys = pl.load(qty_ref, (pl.ds(t, 1), slice(None)))[0]
-        lvs = pl.load(lv_ref, (pl.ds(t, 1), slice(None)))[0]
+
         # tentative reservation walk: subtracting line l before checking
         # line l+1 makes intra-transaction duplicate demand accumulate
         # naturally — no [L, L] tril matrix needed
-        ok = jnp.bool_(True)
-        for l in range(L):
-            s, q, v = slots[l], qtys[l], lvs[l]
-            cur = pl.load(avail_ref, (pl.ds(s, 1),))[0]
-            new = cur - q
-            ok = ok & ((new >= 0) | ~v)
-            pl.store(avail_ref, (pl.ds(s, 1),), jnp.where(v, new, cur)[None])
+        def reserve(j, ok):
+            v = lv_ref[j]
+            new = lane_add(avail_ref, slot_ref[j],
+                           jnp.where(v != 0, -qty_ref[j], 0))
+            return ok & jnp.where((new >= 0) | (v == 0), 1, 0)
+
+        ok = jax.lax.fori_loop(t * L, t * L + L, reserve, jnp.int32(1))
+
         # atomic abort: roll every valid line's reservation back
-        for l in range(L):
-            s, q, v = slots[l], qtys[l], lvs[l]
-            cur = pl.load(avail_ref, (pl.ds(s, 1),))[0]
-            pl.store(avail_ref, (pl.ds(s, 1),),
-                     jnp.where(v & ~ok, cur + q, cur)[None])
-        pl.store(committed_ref, (pl.ds(t, 1),), ok[None])
+        def release(j, carry):
+            lane_add(avail_ref, slot_ref[j],
+                     jnp.where(lv_ref[j] != 0, qty_ref[j], 0))
+            return carry
+
+        @pl.when(ok == 0)
+        def _():
+            jax.lax.fori_loop(t * L, t * L + L, release, 0)
+
+        committed_ref[t] = ok
         return carry
 
     jax.lax.fori_loop(0, n_res_ref[0], txn, 0)
 
 
+def copy_smem(src_ref, dst_ref):
+    """Element-wise SMEM copy (SMEM holds scalars, not vectors)."""
+    def body(i, carry):
+        dst_ref[i] = src_ref[i]
+        return carry
+
+    jax.lax.fori_loop(0, dst_ref.shape[0], body, 0)
+
+
+def _escrow_admit_body(n_res_ref, res_idx_ref, slot_ref, qty_ref, lv_ref,
+                       fast_ref, avail0_hbm, committed_ref, avail_ref):
+    """committed <- fast; avail <- avail0 (one DMA into the VMEM output,
+    which doubles as the running reservation state); then FCFS over the
+    residual transactions."""
+    copy_smem(fast_ref, committed_ref)
+    pltpu.sync_copy(avail0_hbm, avail_ref)
+    fcfs_walk(n_res_ref, res_idx_ref, slot_ref, qty_ref, lv_ref,
+              committed_ref, avail_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def escrow_admit_kernel(avail0: Array, slot: Array, qty: Array,
                         line_valid: Array, fast: Array, res_idx: Array,
                         n_res: Array, *, interpret: bool = False
@@ -177,15 +244,21 @@ def escrow_admit_kernel(avail0: Array, slot: Array, qty: Array,
     Returns ``(committed [B] bool, avail [A])`` where ``avail`` reflects the
     RESIDUAL transactions' reservations only (fast-path demand is settled by
     one vectorized scatter outside — see ops.escrow_admit).
+
+    Layout: ``avail`` rides in ``[rows, 128]`` (:func:`to_lanes`) so each
+    per-line access is one tile-aligned row; the per-line scalars live in
+    SMEM as flat ``[B * L]`` int32 arrays.
     """
     B = slot.shape[0]
     A = avail0.shape[0]
-    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    return pl.pallas_call(
+    lanes = to_lanes(avail0.astype(jnp.int32))
+    committed, avail = pl.pallas_call(
         _escrow_admit_body,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [vmem] * 6,
-        out_specs=[vmem, vmem],
-        out_shape=[jax.ShapeDtypeStruct((B,), jnp.bool_),
-                   jax.ShapeDtypeStruct((A,), jnp.int32)],
+        in_specs=[SMEM] * 6 + [HBM],
+        out_specs=[SMEM, VMEM],
+        out_shape=[jax.ShapeDtypeStruct((B,), jnp.int32),
+                   jax.ShapeDtypeStruct(lanes.shape, jnp.int32)],
         interpret=interpret,
-    )(n_res, res_idx, slot, qty, line_valid, fast, avail0)
+    )(n_res, res_idx, flat_i32(slot), flat_i32(qty), flat_i32(line_valid),
+      flat_i32(fast), lanes)
+    return committed != 0, avail.reshape(-1)[:A]

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils import compat
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -108,7 +107,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),      # running max m
             pltpu.VMEM((block_q,), jnp.float32),      # running denom l
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qh, kh, vh)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils import compat
 
 
 def _merge_kernel(av_ref, ar_ref, ap_ref, bv_ref, br_ref, bp_ref,
@@ -72,7 +71,7 @@ def lattice_merge_kernel(a_valid, a_ver, a_pay, b_valid, b_ver, b_pay,
             jax.ShapeDtypeStruct((R, W), a_pay.dtype),
             jax.ShapeDtypeStruct((R,), jnp.bool_),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(a_valid, a_ver, a_pay, b_valid, b_ver, b_pay)

@@ -75,11 +75,10 @@ def escrow_admit(avail0, slot, qty, line_valid):
     Returns (committed [B] bool, avail [A] int32 after all reservations).
 
     NOT jit-wrapped here: the caller (txn/tpcc.py admit_fcfs) always sits
-    inside a jitted megastep/engine step, and an inner jit would break
-    donation and shard_map tracing.
+    inside a jitted megastep/engine step.
 
     Backend dispatch for Level 2: on TPU the Pallas kernel runs natively
-    (avail in VMEM scratch); off-TPU the same algorithm runs as the jitted
+    (avail resident in VMEM); off-TPU the same algorithm runs as the jitted
     ``residual_fcfs`` fori_loop — interpret-mode Pallas pays ~100x per
     load/store, which would bury the gate's win, while the fallback keeps
     the collapsed sequential depth AND stays bit-exact with the kernel
@@ -129,11 +128,11 @@ def txn_megastep(avail0, slot, qty, line_valid, key_local, cell_local,
 
     NOT jit-wrapped here, like escrow_admit: the caller (txn/tpcc.py
     ``_neworder_fused_effects``) always sits inside a jitted
-    megastep/engine step, and an inner jit would break donation and
-    shard_map tracing.
+    megastep/engine step.
 
     Backend dispatch mirrors escrow_admit: on TPU one Pallas program runs
-    phases 2-4 (avail settles IN-kernel, so no outside scatter); off-TPU the
+    phases 2-3 (avail settles IN-kernel, so no outside scatter) and the
+    phase-4 stamps follow it as jnp; off-TPU the
     admission runs through ``escrow_admit`` (gate + jitted residual_fcfs)
     and phases 3-4 through the vectorized ``megastep_effect_products``
     lowering — same products, bit for bit.

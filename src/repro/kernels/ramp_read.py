@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils import compat
 
 
 def _ramp_read_kernel(req_ts_ref, nlines_ref, ol_ts_ref, ol_vis_ref,
@@ -85,7 +84,7 @@ def ramp_read_kernel(req_ts, nlines, ol_ts, ol_vis, ol_prep, amount, i_id,
             jax.ShapeDtypeStruct((R,), jnp.int32),
             jax.ShapeDtypeStruct((R,), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(req_ts, nlines, ol_ts, ol_vis, ol_prep, amount, i_id)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils import compat
 
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
@@ -110,7 +109,7 @@ def rwkv6_scan_kernel(r, k, v, w, u, s0, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B * H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rh, kh, vh, wh, u, sh)

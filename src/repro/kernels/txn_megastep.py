@@ -1,5 +1,5 @@
 """Pallas TPU kernel: the one-kernel transaction megastep — admission +
-committed effects + RAMP stamping in a single VMEM-resident pipeline.
+committed effects in a single VMEM residency, RAMP stamping beside it.
 
 PR 5 made the closed loop *effects-bound*: the two-level admission wins
 2-2.4x in the micro, but the committed-effect application — the per-district
@@ -18,13 +18,14 @@ the strict-stock New-Order hot path over ONE residency of the hot tiles:
             `avail` is STILL resident: the fast path's reservations settle
             in-place (so `avail` leaves the kernel fully settled, exactly
             `admit_fcfs`'s contract), each transaction picks up its
-            committed per-district rank from a VMEM counter tile (the
+            committed per-district rank from an SMEM counter (the
             batched increment-and-get), and the three stock slabs
-            (decrement / order count / remote count) accumulate into VMEM
-            scratch instead of three whole-table HBM scatter passes;
-  phase 4 — RAMP stamping, vectorized over the whole [B, L] window: the
-            write-set timestamp (`ol_ts`) and the line amounts from the
-            pre-gathered price row.
+            (decrement / order count / remote count) accumulate in VMEM
+            instead of three whole-table HBM scatter passes;
+  phase 4 — RAMP stamping, vectorized jnp over the whole [B, L] window
+            after the kernel (elementwise, so XLA fuses it): the write-set
+            timestamp (`ol_ts`) and the line amounts from the pre-gathered
+            price row.
 
 The kernel returns effect PRODUCTS (rank, per-district counts, stock slabs,
 stamps), not mutated tables: the caller (txn/tpcc.py
@@ -48,22 +49,27 @@ interpret-mode Pallas pays ~100x per load/store, so off-TPU dispatch
 (ops.txn_megastep) runs the gate + `residual_fcfs` + this, bit-exact with
 the kernel (whose interpret-mode path the tests pin against the oracle).
 
-VMEM budget (int32 unless noted): avail [A] + 3 stock slabs [Wl*I] +
-d_count [Wl*D] + rank/committed/fast/res_idx/key [B] + 8 x [B, L] line
-tiles (slot/qty/lv/cell/loc/rem/ol_ts/amount f32) + ts/price. At spec scale
-on the production mesh (A ~ 712k cells, 2 local warehouses x 100k items,
-B = 32) that is ~5.3 MB — inside the ~16 MB/core VMEM (asserted by the
-dry-run's ``megastep_fused`` cell).
+Memory placement: VMEM holds avail [A] and the three stock slabs [Wl*I],
+each as ``[rows, 128]`` int32 (escrow_admit.to_lanes) — 16 bytes per local
+stock cell, about 16 MB at 10 local warehouses, the most one v5e core's
+default scoped VMEM takes (tests/test_tpu_compile.py: 11 is refused;
+streaming the slabs over a grid lifts the limit). SMEM holds the scalars:
+the per-transaction residual order / verdicts / keys / ranks, the
+per-district counters, and the flat [B * L] line arrays.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .escrow_admit import (HBM, LANES, SMEM, VMEM, copy_smem, fcfs_walk,
+                           flat_i32, lane_add, lane_rows, to_lanes)
 
 Array = jax.Array
 
@@ -140,97 +146,69 @@ def megastep_effect_products(committed: Array, qty: Array, line_valid: Array,
 
 
 def _txn_megastep_body(n_res_ref, res_idx_ref, slot_ref, qty_ref, lv_ref,
-                       fast_ref, avail0_ref, key_ref, cell_ref, loc_ref,
-                       rem_ref, ts_ref, price_ref,
-                       committed_ref, avail_ref, rank_ref, dcnt_ref,
-                       dec_ref, cnt_ref, rcnt_ref, olts_ref, amt_ref):
-    """Four phases over one VMEM residency of the hot tiles. ``avail_ref``
-    doubles as the running reservation state across phases 2-3;
-    ``dcnt_ref`` doubles as the per-district increment-and-get counter."""
-    committed_ref[...] = fast_ref[...]
-    avail_ref[...] = avail0_ref[...]
-    dcnt_ref[...] = jnp.zeros(dcnt_ref.shape, jnp.int32)
-    dec_ref[...] = jnp.zeros(dec_ref.shape, jnp.int32)
-    cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.int32)
-    rcnt_ref[...] = jnp.zeros(rcnt_ref.shape, jnp.int32)
-    L = slot_ref.shape[1]
+                       fast_ref, key_ref, cell_ref, loc_ref, rem_ref,
+                       avail0_hbm,
+                       committed_ref, rank_ref, dcnt_ref,
+                       avail_ref, dec_ref, cnt_ref, rcnt_ref):
+    """Phases 2-3 over one VMEM residency of the hot tiles. ``avail_ref``
+    doubles as the running reservation state across both phases;
+    ``dcnt_ref`` (SMEM) doubles as the per-district increment-and-get
+    counter."""
+    copy_smem(fast_ref, committed_ref)
+    pltpu.sync_copy(avail0_hbm, avail_ref)
 
-    # ---- phase 2: residual FCFS (the escrow_admit walk, verbatim) ----------
-    def residual_txn(i, carry):
-        t = res_idx_ref[i]
-        slots = pl.load(slot_ref, (pl.ds(t, 1), slice(None)))[0]
-        qtys = pl.load(qty_ref, (pl.ds(t, 1), slice(None)))[0]
-        lvs = pl.load(lv_ref, (pl.ds(t, 1), slice(None)))[0]
-        ok = jnp.bool_(True)
-        for l in range(L):
-            s, q, v = slots[l], qtys[l], lvs[l]
-            cur = pl.load(avail_ref, (pl.ds(s, 1),))[0]
-            new = cur - q
-            ok = ok & ((new >= 0) | ~v)
-            pl.store(avail_ref, (pl.ds(s, 1),), jnp.where(v, new, cur)[None])
-        for l in range(L):
-            s, q, v = slots[l], qtys[l], lvs[l]
-            cur = pl.load(avail_ref, (pl.ds(s, 1),))[0]
-            pl.store(avail_ref, (pl.ds(s, 1),),
-                     jnp.where(v & ~ok, cur + q, cur)[None])
-        pl.store(committed_ref, (pl.ds(t, 1),), ok[None])
+    def zero_keys(k, carry):
+        dcnt_ref[k] = 0
         return carry
 
-    jax.lax.fori_loop(0, n_res_ref[0], residual_txn, 0)
+    jax.lax.fori_loop(0, dcnt_ref.shape[0], zero_keys, 0)
+    for ref in (dec_ref, cnt_ref, rcnt_ref):
+        ref[...] = jnp.zeros(ref.shape, jnp.int32)
+    B = committed_ref.shape[0]
+    L = slot_ref.shape[0] // B
+
+    # ---- phase 2: residual FCFS (the escrow_admit walk, verbatim) ----------
+    fcfs_walk(n_res_ref, res_idx_ref, slot_ref, qty_ref, lv_ref,
+              committed_ref, avail_ref)
 
     # ---- phase 3: committed effects, batch order, avail still resident -----
-    B = slot_ref.shape[0]
-
     def effect_txn(t, carry):
-        c = pl.load(committed_ref, (pl.ds(t, 1),))[0]
-        fast_t = pl.load(fast_ref, (pl.ds(t, 1),))[0]
+        c = committed_ref[t]
+        fast_t = fast_ref[t]
         # per-district increment-and-get: rank is the count of committed
         # earlier same-key txns (stored for every txn, like the scan path —
         # aborted rows' o_ids are computed there too and dropped downstream)
-        key = pl.load(key_ref, (pl.ds(t, 1),))[0]
-        kcnt = pl.load(dcnt_ref, (pl.ds(key, 1),))[0]
-        pl.store(rank_ref, (pl.ds(t, 1),), kcnt[None])
-        pl.store(dcnt_ref, (pl.ds(key, 1),),
-                 (kcnt + jnp.where(c, 1, 0))[None])
-        slots = pl.load(slot_ref, (pl.ds(t, 1), slice(None)))[0]
-        qtys = pl.load(qty_ref, (pl.ds(t, 1), slice(None)))[0]
-        lvs = pl.load(lv_ref, (pl.ds(t, 1), slice(None)))[0]
-        cells = pl.load(cell_ref, (pl.ds(t, 1), slice(None)))[0]
-        locs = pl.load(loc_ref, (pl.ds(t, 1), slice(None)))[0]
-        rems = pl.load(rem_ref, (pl.ds(t, 1), slice(None)))[0]
-        for l in range(L):
-            q, v = qtys[l], lvs[l]
+        key = key_ref[t]
+        kcnt = dcnt_ref[key]
+        rank_ref[t] = kcnt
+        dcnt_ref[key] = kcnt + c
+
+        def line(j, carry):
+            q = qty_ref[j]
+
             # settle the fast path's reservation in-place: avail leaves the
             # kernel fully settled (admit_fcfs's contract), no outside
             # scatter needed
-            s = slots[l]
-            cur = pl.load(avail_ref, (pl.ds(s, 1),))[0]
-            pl.store(avail_ref, (pl.ds(s, 1),),
-                     jnp.where(v & fast_t, cur - q, cur)[None])
-            # stock slabs: admitted local lines; masked lines redirect to
-            # cell 0 adding 0 (exact for integer accumulation)
-            m = c & locs[l]
-            cell = jnp.where(m, cells[l], 0)
-            d0 = pl.load(dec_ref, (pl.ds(cell, 1),))[0]
-            pl.store(dec_ref, (pl.ds(cell, 1),),
-                     (d0 + jnp.where(m, q, 0))[None])
-            c0 = pl.load(cnt_ref, (pl.ds(cell, 1),))[0]
-            pl.store(cnt_ref, (pl.ds(cell, 1),),
-                     (c0 + jnp.where(m, 1, 0))[None])
-            r0 = pl.load(rcnt_ref, (pl.ds(cell, 1),))[0]
-            pl.store(rcnt_ref, (pl.ds(cell, 1),),
-                     (r0 + jnp.where(m & rems[l], 1, 0))[None])
+            @pl.when((lv_ref[j] != 0) & (fast_t != 0))
+            def _():
+                lane_add(avail_ref, slot_ref[j], -q)
+
+            # stock slabs: admitted local lines only
+            @pl.when((c != 0) & (loc_ref[j] != 0))
+            def _():
+                cell = cell_ref[j]
+                lane_add(dec_ref, cell, q)
+                lane_add(cnt_ref, cell, 1)
+                lane_add(rcnt_ref, cell, rem_ref[j])
+            return carry
+
+        jax.lax.fori_loop(t * L, t * L + L, line, 0)
         return carry
 
     jax.lax.fori_loop(0, B, effect_txn, 0)
 
-    # ---- phase 4: RAMP stamps, vectorized over the whole window ------------
-    lv = lv_ref[...]
-    olts_ref[...] = jnp.where(lv, ts_ref[...][:, None], -1).astype(jnp.int32)
-    amt_ref[...] = jnp.where(
-        lv, price_ref[...] * qty_ref[...].astype(price_ref.dtype), 0.0)
 
-
+@functools.partial(jax.jit, static_argnames=("n_keys", "n_cells", "interpret"))
 def txn_megastep_kernel(avail0: Array, slot: Array, qty: Array,
                         line_valid: Array, fast: Array, res_idx: Array,
                         n_res: Array, key_local: Array, cell_local: Array,
@@ -238,34 +216,44 @@ def txn_megastep_kernel(avail0: Array, slot: Array, qty: Array,
                         ramp_ts: Array, price_row: Array, *,
                         n_keys: int, n_cells: int,
                         interpret: bool = False) -> MegastepOut:
-    """The fused megastep (phases 2-4; the gate runs outside as vectorized
-    jnp). ``avail0`` [A] int32; ``slot``/``qty``/``line_valid`` [B, L];
-    ``fast``/``res_idx``/``n_res`` from the gate + residual_order;
-    ``key_local`` [B] district keys in [0, n_keys); ``cell_local`` [B, L]
-    local stock cells in [0, n_cells) (masked by ``local_line``);
-    ``remote_line`` [B, L]; ``ramp_ts`` [B] int32; ``price_row`` [B, L] f32.
+    """The fused megastep (phases 2-3 in the kernel; the gate runs before
+    it and the phase-4 stamps after it as vectorized jnp). ``avail0`` [A]
+    int32; ``slot``/``qty``/``line_valid`` [B, L]; ``fast``/``res_idx``/
+    ``n_res`` from the gate + residual_order; ``key_local`` [B] district
+    keys in [0, n_keys); ``cell_local`` [B, L] local stock cells in
+    [0, n_cells) (masked by ``local_line``); ``remote_line`` [B, L];
+    ``ramp_ts`` [B] int32; ``price_row`` [B, L] f32.
 
     Returns :class:`MegastepOut` with ``avail`` FULLY settled (fast +
     residual reservations — bit-identical to ``admit_fcfs``'s output).
+
+    Layout: ``avail`` and the three stock slabs ride in ``[rows, 128]``
+    int32 (escrow_admit.to_lanes), so every per-line access is one
+    tile-aligned row; per-transaction and per-line scalars, the verdicts,
+    ranks and district counters live in SMEM.
     """
     B, L = slot.shape
     A = avail0.shape[0]
-    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    f32 = price_row.dtype
-    out = pl.pallas_call(
+    lanes = to_lanes(avail0.astype(jnp.int32))
+    slab = jax.ShapeDtypeStruct((lane_rows(n_cells), LANES), jnp.int32)
+    committed, rank, d_count, avail, dec, cnt, rcnt = pl.pallas_call(
         _txn_megastep_body,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [vmem] * 12,
-        out_specs=[vmem] * 9,
-        out_shape=[jax.ShapeDtypeStruct((B,), jnp.bool_),
-                   jax.ShapeDtypeStruct((A,), jnp.int32),
+        in_specs=[SMEM] * 10 + [HBM],
+        out_specs=[SMEM] * 3 + [VMEM] * 4,
+        out_shape=[jax.ShapeDtypeStruct((B,), jnp.int32),
                    jax.ShapeDtypeStruct((B,), jnp.int32),
                    jax.ShapeDtypeStruct((n_keys,), jnp.int32),
-                   jax.ShapeDtypeStruct((n_cells,), jnp.int32),
-                   jax.ShapeDtypeStruct((n_cells,), jnp.int32),
-                   jax.ShapeDtypeStruct((n_cells,), jnp.int32),
-                   jax.ShapeDtypeStruct((B, L), jnp.int32),
-                   jax.ShapeDtypeStruct((B, L), f32)],
+                   jax.ShapeDtypeStruct(lanes.shape, jnp.int32),
+                   slab, slab, slab],
         interpret=interpret,
-    )(n_res, res_idx, slot, qty, line_valid, fast, avail0, key_local,
-      cell_local, local_line, remote_line, ramp_ts, price_row)
-    return MegastepOut(*out)
+    )(n_res, res_idx, flat_i32(slot), flat_i32(qty), flat_i32(line_valid),
+      flat_i32(fast), key_local.astype(jnp.int32), flat_i32(cell_local),
+      flat_i32(local_line), flat_i32(remote_line), lanes)
+    cells = lambda x: x.reshape(-1)[:n_cells]
+    # ---- phase 4: RAMP stamps, vectorized over the whole window ------------
+    ol_ts = jnp.where(line_valid, ramp_ts[:, None], -1).astype(jnp.int32)
+    amount = jnp.where(line_valid,
+                       price_row * qty.astype(price_row.dtype), 0.0)
+    return MegastepOut(committed != 0, avail.reshape(-1)[:A], rank,
+                       d_count, cells(dec), cells(cnt), cells(rcnt), ol_ts,
+                       amount)

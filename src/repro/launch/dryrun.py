@@ -31,8 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.utils import compat
 from repro.configs import registry
+from repro.kernels.escrow_admit import LANES, lane_rows
 from repro.models.config import SHAPES, ModelConfig, ShapeConfig
 from repro.models.sharding import Rules, param_pspecs
 from repro.optim import adamw, coord
@@ -101,7 +101,7 @@ def lower_prefill(arch: str, cfg: ModelConfig, shape: ShapeConfig, mesh,
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
     batch_sh = jax.tree.map(lambda _: NamedSharding(mesh, P(batch_axes)),
                             batch_specs)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(prefill, in_shardings=(param_sh, batch_sh)).lower(
             params_abs, batch_specs), None
 
@@ -151,7 +151,7 @@ def lower_decode(arch: str, cfg: ModelConfig, shape: ShapeConfig, mesh):
     token_sh = NamedSharding(
         mesh, P(batch_axes) if _shape_divisible(shape.global_batch, mesh,
                                                 batch_axes) else P())
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(decode, in_shardings=(param_sh, cache_sh, token_sh)
                        ).lower(params_abs, cache_specs, token_spec), None
 
@@ -273,7 +273,7 @@ def analyze(lowered, mesh, label: str, trip_counts=(),
     except Exception as e:  # pragma: no cover
         out["memory"] = {"error": str(e)}
     try:
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         out["cost"] = {k: cost.get(k) for k in
                        ("flops", "bytes accessed", "transcendentals",
                         "optimal_seconds") if k in cost}
@@ -393,7 +393,9 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_label: str,
             # TWO-LEVEL ADMISSION at spec scale: the contention-gated
             # escrow hot path (admission="kernel") must also compile
             # collective-free, and the availability vector the Pallas FCFS
-            # kernel keeps resident in VMEM must fit a TPU core's ~16 MB
+            # kernel keeps resident in VMEM ([rows, 128] int32) must fit a
+            # TPU core's 16 MiB default scoped VMEM (arithmetic here; Mosaic
+            # itself checks it in tests/test_tpu_compile.py)
             adm = analyze(admission, mesh, "tpcc-escrow-admission", ())
             cell["escrow_admission"] = adm
             if adm["collectives"]["counts"]:
@@ -403,16 +405,18 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_label: str,
             A = (eng_admit.hot_keys.shape[0]
                  + eng_admit.w_per_shard * eng_admit.scale.n_items + 1)
             adm["avail_cells"] = A
-            adm["avail_vmem_bytes"] = 4 * A
-            if 4 * A > 16 * 2 ** 20:
+            adm["avail_vmem_bytes"] = 4 * LANES * lane_rows(A)
+            if adm["avail_vmem_bytes"] > 16 * 2 ** 20:
                 raise AssertionError(
-                    f"admission avail vector ({4 * A / 2**20:.1f} MB) "
+                    f"admission avail vector "
+                    f"({adm['avail_vmem_bytes'] / 2**20:.1f} MB) "
                     f"exceeds the ~16 MB VMEM budget")
             # the ONE-KERNEL megastep (effects="fused") at spec scale: the
             # fused admission+effects+stamps hot path must also compile
-            # collective-free, and the kernel's WHOLE VMEM working set —
-            # avail + the three stock slabs + the district counter tile +
-            # the per-batch line tiles — must fit a TPU core's ~16 MB
+            # collective-free, and the kernel's VMEM working set — avail +
+            # the three stock slabs, each [rows, 128] int32 (the scalars
+            # live in SMEM) — must fit a TPU core's 16 MiB default scoped
+            # VMEM
             fm = analyze(fused_effects, mesh, "tpcc-megastep-fused", ())
             cell["megastep_fused"] = fm
             if fm["collectives"]["counts"]:
@@ -422,10 +426,8 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_label: str,
             sc = eng_fused.scale
             Wl = eng_fused.w_per_shard
             Af = (eng_fused.hot_keys.shape[0] + Wl * sc.n_items + 1)
-            # int32 words: avail + 3 stock slabs + d_count + 5 [B] vectors
-            # (committed/fast/rank/res_idx/key) + 9 [B, L] line tiles
-            vmem = 4 * (Af + 3 * Wl * sc.n_items + Wl * sc.districts
-                        + 5 * bps + 9 * bps * sc.max_lines)
+            vmem = 4 * LANES * (lane_rows(Af)
+                                + 3 * lane_rows(Wl * sc.n_items))
             fm["megastep_vmem_bytes"] = vmem
             if vmem > 16 * 2 ** 20:
                 raise AssertionError(

@@ -21,7 +21,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.utils import compat
 
 from . import layers as L
 from .config import ModelConfig
@@ -372,10 +371,10 @@ def moe_apply_a2a(params: dict, x: Array, cfg: ModelConfig, rules: Rules
     expert axes; falls back to blocked dispatch when the expert axis is
     absent or sized 1.
     """
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     expert_axis = rules.expert
     if (not rules.enabled or expert_axis is None
-            or mesh is None or expert_axis not in getattr(mesh, "shape", {})
+            or mesh.empty or expert_axis not in mesh.shape
             or mesh.shape[expert_axis] == 1):
         return moe_apply(params, x, cfg, rules)
 
@@ -463,7 +462,7 @@ def moe_apply_a2a(params: dict, x: Array, cfg: ModelConfig, rules: Rules
             dropped = jax.lax.psum(dropped, a)
         return (out.reshape(B_loc, S, d), aux, load, dropped)
 
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         body,
         in_specs=(P(), P(expert_axis, None, None), P(expert_axis, None, None),
                   P(expert_axis, None, None), P(batch_axes, None, None)),

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils import compat
 from repro.models.sharding import Rules, opt_state_pspecs, param_pspecs
 
 from . import adamw, compression
@@ -72,11 +71,11 @@ def _under_mesh(fn: Optional[Callable], mesh: Mesh) -> Optional[Callable]:
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return fn(*args, **kwargs)
 
     def lower(*args, **kwargs):
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return fn.lower(*args, **kwargs)
 
     wrapped.lower = lower
@@ -294,11 +293,11 @@ def _build_deferred(model_cfg, rules, mesh, coord, opt_cfg, loss_fn,
         P(), P(pod), P(pod), P(pod))
     batch_pod_specs = jax.tree.map(lambda _: P(pod), batch_specs)
 
-    sm_step = compat.shard_map(step_local, mesh=mesh,
+    sm_step = jax.shard_map(step_local, mesh=mesh,
                             in_specs=(manual_specs, batch_pod_specs),
                             out_specs=manual_specs,
                             axis_names={pod}, check_vma=False)
-    sm_merge = compat.shard_map(merge_local, mesh=mesh,
+    sm_merge = jax.shard_map(merge_local, mesh=mesh,
                              in_specs=(manual_specs,),
                              out_specs=manual_specs,
                              axis_names={pod}, check_vma=False)

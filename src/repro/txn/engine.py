@@ -29,12 +29,12 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.lattice import EscrowCounter, HotSetEscrow
 from repro.core.planner import CoordClass, plan as plan_specs
 from repro.core.analyzer import Strategy
-from repro.utils.compat import shard_map
 from repro.utils.hlo import assert_no_collectives, collective_stats
 
 from . import ramp, tpcc

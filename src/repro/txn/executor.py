@@ -50,13 +50,13 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 import contextlib
 
 from repro.core.lattice import EscrowCounter
 from repro.core.planner import CoordClass
 from repro.obs import metrics as obsm
-from repro.utils.compat import shard_map
 from repro.utils.hlo import assert_no_collectives, collective_stats
 
 from . import ramp, tpcc

@@ -529,11 +529,11 @@ def make_escrow_shares(s_quantity, num_replicas: int):
 
 ADMISSION_MODES = ("auto", "scan", "kernel")
 
-# no-autotune fallback threshold: below this per-shard batch the B-step scan
-# is cheaper than the gate's pre-pass + kernel launch; above it the gate
+# no-autotune threshold: below this per-shard batch the B-step scan is
+# cheaper than the gate's pre-pass + kernel launch; above it the gate
 # collapses the sequential depth to the contended handful. The live "auto"
 # decision is the measured resolve_admission_cutover below; this constant is
-# what it falls back to when autotuning is disabled or fails.
+# what "auto" uses when autotuning is disabled.
 AUTO_KERNEL_MIN_BATCH = 64
 
 # one flip disables the measured cut-over everywhere (tests pin it off to
@@ -557,44 +557,39 @@ def resolve_admission_cutover(batch: int, n_lines: int = 15, *,
     probe arrays are fresh concrete values — calling the two jitted probes
     while an outer trace is live is legal and leaves no residue in the outer
     program (the resolved mode is a static Python string, exactly like the
-    constant it replaces). Any failure (e.g. an exotic backend that refuses
-    one strategy) falls back to the constant threshold.
+    constant it replaces). A strategy that fails to compile or run raises:
+    the probe never hides a broken kernel behind the other strategy.
     """
     key = (jax.default_backend(), batch, n_lines)
     hit = _CUTOVER_CACHE.get(key)
     if hit is not None:
         return hit
-    fallback = "kernel" if batch >= AUTO_KERNEL_MIN_BATCH else "scan"
-    try:
-        import time
+    import time
 
-        rng = np.random.default_rng(0)
-        # the TPC-C regime the engine actually runs: plentiful stock under a
-        # skewed access profile, contention the exception (the CALM gate's
-        # design point) — probing a starved problem instead would measure a
-        # workload the hot path never sees and flatter the scan
-        avail0 = jnp.asarray(rng.integers(100, 500, size=cells), jnp.int32)
-        slot = jnp.asarray(
-            (cells * rng.power(4.0, size=(batch, n_lines))).astype(np.int64)
-            % cells, jnp.int32)
-        qty = jnp.asarray(rng.integers(1, 10, size=(batch, n_lines)),
-                          jnp.int32)
-        lv = jnp.asarray(rng.random((batch, n_lines)) < 0.8)
-        # small batches run in tens of microseconds — repeat enough that the
-        # measured wall is timer-resolvable, not scheduler noise
-        reps = max(trials, 1024 // max(batch, 1))
-        walls = {}
-        for mode in ("scan", "kernel"):
-            probe = jax.jit(lambda a, s, q, v, mode=mode: admit_fcfs(
-                a, s, q, v, admission=mode))
-            jax.block_until_ready(probe(avail0, slot, qty, lv))  # compile
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                jax.block_until_ready(probe(avail0, slot, qty, lv))
-            walls[mode] = time.perf_counter() - t0
-        choice = min(walls, key=walls.get)
-    except Exception:
-        choice = fallback
+    rng = np.random.default_rng(0)
+    # the TPC-C regime the engine actually runs: plentiful stock under a
+    # skewed access profile, contention the exception (the CALM gate's
+    # design point) — probing a starved problem instead would measure a
+    # workload the hot path never sees and flatter the scan
+    avail0 = jnp.asarray(rng.integers(100, 500, size=cells), jnp.int32)
+    slot = jnp.asarray(
+        (cells * rng.power(4.0, size=(batch, n_lines))).astype(np.int64)
+        % cells, jnp.int32)
+    qty = jnp.asarray(rng.integers(1, 10, size=(batch, n_lines)), jnp.int32)
+    lv = jnp.asarray(rng.random((batch, n_lines)) < 0.8)
+    # small batches run in tens of microseconds — repeat enough that the
+    # measured wall is timer-resolvable, not scheduler noise
+    reps = max(trials, 1024 // max(batch, 1))
+    walls = {}
+    for mode in ("scan", "kernel"):
+        probe = jax.jit(lambda a, s, q, v, mode=mode: admit_fcfs(
+            a, s, q, v, admission=mode))
+        jax.block_until_ready(probe(avail0, slot, qty, lv))  # compile
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            jax.block_until_ready(probe(avail0, slot, qty, lv))
+        walls[mode] = time.perf_counter() - t0
+    choice = min(walls, key=walls.get)
     _CUTOVER_CACHE[key] = choice
     return choice
 

@@ -31,9 +31,9 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.utils.compat import shard_map
 from repro.utils.hlo import collective_stats
 
 from . import ramp, tpcc

@@ -9,7 +9,6 @@ from benchmarks.roofline import (analytic_flops, analytic_hbm_bytes, build,
                                  loop_scaled_collective_bytes,
                                  trip_counts_for)
 from repro.configs import registry
-from repro.utils import compat
 from repro.models.config import SHAPES
 from repro.utils.hlo import (_parse_replica_groups, collective_stats,
                              cross_pod_collectives, shape_bytes)
@@ -82,7 +81,7 @@ def test_loop_scaling_against_unrolled():
 
     x = jax.ShapeDtypeStruct((D, D), jnp.float32)
     w = jax.ShapeDtypeStruct((L, D, D), jnp.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t1 = jax.jit(scanned).lower(x, w).compile().as_text()
         t2 = jax.jit(unrolled).lower(x, w).compile().as_text()
     b_scan = loop_scaled_collective_bytes(t1, [L])

@@ -12,7 +12,6 @@ from repro.configs import registry
 from repro.models import layers as L
 from repro.models import moe
 from repro.models.sharding import Rules
-from repro.utils import compat
 
 RULES = Rules.disabled()
 
@@ -137,7 +136,6 @@ import jax, jax.numpy as jnp
 from repro.configs import registry
 from repro.models import moe
 from repro.models.sharding import Rules
-from repro.utils import compat
 mesh = jax.make_mesh((1, 2, 4), ("pod", "data", "model"))
 cfg0 = registry.get_config("olmoe-1b-7b").reduced()
 cfg_ref = dataclasses.replace(cfg0, capacity_factor=16.0)
@@ -146,7 +144,7 @@ params = registry.init_params(jax.random.PRNGKey(0), cfg_ref)
 rules = Rules(batch=("pod", "data"))
 x = jax.random.normal(jax.random.PRNGKey(2), (4, 16, cfg0.d_model))
 lp = jax.tree.map(lambda p: p[0], params["layers"])
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     out_ref, st_ref = jax.jit(
         lambda p, xx: moe.moe_apply(p, xx, cfg_ref, rules))(lp["moe"], x)
     out_a2a, st_a2a = jax.jit(
@@ -188,7 +186,7 @@ def test_a2a_falls_back_without_expert_axis():
     lp = jax.tree.map(lambda p: p[0], params["layers"])
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 8, cfg.d_model))
     mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out, stats = moe.moe_apply_a2a(lp["moe"], x, cfg,
                                        Rules(batch=("pod", "data")))
     ref, _ = moe.moe_apply(lp["moe"], x, cfg, Rules.disabled())
