@@ -76,11 +76,9 @@ def roofline_section(rows: list[dict]) -> str:
 
 def obs_section(snap: dict) -> str:
     """§Observability from an ObsSession snapshot (repro/obs): per-txn-type
-    latency-proxy quantiles, the phase wall breakdown, and the coordination
-    ledger's per-phase bytes."""
-    out = ["### §Observability — metrics lattice + phase tracer + "
-           "coordination ledger",
-           ""]
+    latency-proxy quantiles and the coordination ledger's per-phase
+    bytes."""
+    out = ["### §Observability — metrics lattice + coordination ledger", ""]
     lat = snap.get("latency")
     if lat:
         unit = "s" if any("p50_s" in r for r in lat.values()) else "steps"
@@ -92,14 +90,6 @@ def obs_section(snap: dict) -> str:
             p50 = r.get("p50_s", r["p50_steps"])
             p99 = r.get("p99_s", r["p99_steps"])
             out.append(f"| {name} | {r['count']} | {p50:.3g} | {p99:.3g} |")
-        out.append("")
-    spans = snap.get("spans", {}).get("phases", {})
-    if spans:
-        out += ["Phase breakdown (host wall per tracer span):", "",
-                "| phase | calls | total ms | share |", "|---|---|---|---|"]
-        for name, p in spans.items():
-            out.append(f"| {name} | {p['count']} | "
-                       f"{p['total_s'] * 1e3:.1f} | {p['share']:.0%} |")
         out.append("")
     led = snap.get("ledger")
     if led:

@@ -91,7 +91,7 @@ def chaos_demo(args) -> None:
           f"(extended ledger exact: {led['reservations_exact']})")
     print("audit:", rep.describe())
 
-    obs = ObsSession(metrics=False, trace=False)
+    obs = ObsSession(metrics=False)
     obs.record_heartbeat_lags(sim.monitor.detection_lags())
     print("detection latency (windows):", obs.detection_latency_summary())
     if args.json:
@@ -114,7 +114,7 @@ def main() -> None:
                          "throughput + detection latency")
     ap.add_argument("--json", default="", metavar="PATH",
                     help="write the full observability snapshot (metrics "
-                         "lattice + phase spans + coordination ledger) to "
+                         "lattice + coordination ledger) to "
                          "PATH after the instrumented full-mix run")
     args = ap.parse_args()
     use_compile_cache()
@@ -168,9 +168,9 @@ def main() -> None:
     print(f"dispatch: {dstats.throughput:,.0f} txn/s -> fused executor is "
           f"{stats.throughput / max(dstats.throughput, 1e-9):.1f}x")
 
-    print("\n-- observability plane (metrics lattice + tracer + ledger) --")
+    print("\n-- observability plane (metrics lattice + ledger) --")
     from repro.obs import ObsSession
-    obs = ObsSession(metrics=True, trace=True, ledger=True)
+    obs = ObsSession(metrics=True, ledger=True)
     so = engine.shard_state(init_state(scale))
     so, ostats = run_mixed_loop(
         engine, so, batch_per_shard=args.batch_per_shard,

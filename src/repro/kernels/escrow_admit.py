@@ -259,6 +259,7 @@ def escrow_admit_kernel(avail0: Array, slot: Array, qty: Array,
         out_shape=[jax.ShapeDtypeStruct((B,), jnp.int32),
                    jax.ShapeDtypeStruct(lanes.shape, jnp.int32)],
         interpret=interpret,
+        name="escrow_admit_kernel",
     )(n_res, res_idx, flat_i32(slot), flat_i32(qty), flat_i32(line_valid),
       flat_i32(fast), lanes)
     return committed != 0, avail.reshape(-1)[:A]

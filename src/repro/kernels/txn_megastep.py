@@ -246,6 +246,7 @@ def txn_megastep_kernel(avail0: Array, slot: Array, qty: Array,
                    jax.ShapeDtypeStruct(lanes.shape, jnp.int32),
                    slab, slab, slab],
         interpret=interpret,
+        name="txn_megastep_kernel",
     )(n_res, res_idx, flat_i32(slot), flat_i32(qty), flat_i32(line_valid),
       flat_i32(fast), key_local.astype(jnp.int32), flat_i32(cell_local),
       flat_i32(local_line), flat_i32(remote_line), lanes)

@@ -7,9 +7,9 @@ Three pillars, one session object:
   item-access histogram), fed by deferred per-chunk recorder programs whose
   lattice joins commute — bit-identical to inline recording, zero dispatches
   in the timed loop;
-* :mod:`repro.obs.trace` — the phase tracer (span wall clocks +
-  ``jax.profiler.TraceAnnotation`` around megastep / outbox-drain /
-  share-refresh / audit);
+* :mod:`repro.obs.trace` — the executor's host spans and named device
+  phases, on the profiler's clock, and the scope table that maps a
+  program's instructions to its phases;
 * :mod:`repro.obs.ledger` — the coordination ledger (per-phase collective
   counts and bytes-on-wire from compiled HLO; hot phases budgeted at zero).
 
@@ -31,10 +31,9 @@ from .metrics import (N_TXN_TYPES, OBS_BINS, TXN_TYPES, ObsMetrics,
                       item_access_summary, latency_summary, make_obs_metrics,
                       obs_metrics_join, obs_metrics_specs,
                       obs_partition_specs)
-from .trace import PhaseTracer
 
 __all__ = [
-    "ObsSession", "PhaseTracer", "CoordinationLedger", "build_ledger",
+    "ObsSession", "CoordinationLedger", "build_ledger",
     "ObsMetrics", "make_obs_metrics", "init_obs_metrics", "obs_metrics_join",
     "obs_metrics_specs", "obs_partition_specs", "add_cold_rejects",
     "latency_summary", "item_access_summary", "heartbeat_lag_histogram",
@@ -49,17 +48,13 @@ class ObsSession:
     ``metrics=True`` threads the on-device :class:`ObsMetrics` lattice
     through the fused megastep (write-only: the transaction path never reads
     it, so final state is bit-identical to a metrics-off run);
-    ``sync_spans=True`` blocks inside tracer spans for true per-phase device
-    attribution (a measurement mode — perturbs timing, never results);
     ``ledger=True`` builds the coordination ledger at finish (compiles the
     phase programs once, outside any timed region).
     """
 
-    def __init__(self, metrics: bool = True, trace: bool = True,
-                 sync_spans: bool = False, ledger: bool = False):
+    def __init__(self, metrics: bool = True, ledger: bool = False):
         self.wants_metrics = metrics
         self.wants_ledger = ledger
-        self.tracer = PhaseTracer(enabled=trace, sync=sync_spans)
         self.device_metrics: ObsMetrics | None = None
         self.metrics: ObsMetrics | None = None   # host copy, set at finish
         self.heartbeat_lag = None                # HistogramLattice | None
@@ -69,12 +64,6 @@ class ObsSession:
         self._run_kw: dict = {}
 
     # -- driver-side hooks ---------------------------------------------------
-
-    def span(self, phase: str):
-        return self.tracer.span(phase)
-
-    def maybe_sync(self, value):
-        return self.tracer.maybe_sync(value)
 
     def init_metrics(self, engine) -> ObsMetrics | None:
         """Called by the executor at run start; returns the device pytree the
@@ -138,8 +127,8 @@ class ObsSession:
 
     def snapshot(self) -> dict:
         """The full JSON-ready snapshot: closed-loop stats, per-txn-type
-        latency quantiles, counters, item-access profile, phase spans, and
-        the coordination ledger."""
+        latency quantiles, counters, item-access profile, and the
+        coordination ledger."""
         snap: dict = {"schema": "repro.obs/1"}
         if self.stats is not None:
             s = self.stats
@@ -160,7 +149,6 @@ class ObsSession:
             snap["item_access"] = self.item_access_summary()
         if self.heartbeat_lag is not None:
             snap["detection_latency"] = self.detection_latency_summary()
-        snap["spans"] = self.tracer.snapshot()
         if self.ledger is not None:
             snap["ledger"] = self.ledger.snapshot()
         return snap
@@ -169,7 +157,7 @@ class ObsSession:
         return json.dumps(self.snapshot(), **{"indent": 2, **kw})
 
     def dashboard(self) -> str:
-        """Text view: latency table + spans + ledger."""
+        """Text view: latency table + ledger."""
         parts = []
         lat = self.latency_summary()
         if lat:
@@ -188,8 +176,6 @@ class ObsSession:
                     p99 = f"{row['p99_steps']:>8.1f}st"
                 parts.append(f"  {name:<14}{row['count']:>9}{p50:>10}"
                              f"{p99:>10}")
-        if self.tracer.phases:
-            parts.append(self.tracer.dashboard())
         if self.ledger is not None:
             parts.append(self.ledger.table())
         return "\n".join(parts)

@@ -1,111 +1,183 @@
-"""Phase tracer for the closed-loop drivers (obs pillar 2).
+"""Spans and named phases of the executor, on the profiler's clock.
 
-A deliberately small span API: the chunk loop in ``txn/drivers.run_loop`` /
-``txn/executor.FusedExecutor.run*`` wraps each phase — ``megastep``,
-``outbox-drain``, ``share-refresh``, ``audit`` — in
-:meth:`PhaseTracer.span`, which emits a ``jax.profiler.TraceAnnotation``
-(visible in a TensorBoard/perfetto trace when the JAX profiler is active)
-and accumulates host wall clocks per phase.
+Two kinds of mark, both inert unless a JAX profiler session is active:
 
-Because JAX dispatch is asynchronous, a span around an un-synced device call
-measures *dispatch* time, not device time — honest for spotting host-side
-stalls, misleading for device attribution. ``sync=True`` makes the caller
-block inside each span (via :meth:`maybe_sync`), giving true per-phase wall
-time at the cost of one device sync per phase — a measurement mode, never
-the default, and never active in the overhead benchmark.
+* **host spans** — :func:`span` is a ``jax.profiler.TraceAnnotation``: a
+  TraceMe event on the profiler's host plane, nested on the calling thread,
+  on the same clock as the device planes. ``FusedExecutor.run`` /
+  ``run_escrow`` mark every phase of a call with the ``EXEC_*`` names, so a
+  trace says what the host did in each stretch of device idleness.
+* **device phases** — :func:`phase` is a ``jax.named_scope``: it puts its
+  name into the HLO metadata (``op_name="jit(f)/.../txn.neworder/..."``) of
+  every operation traced under it and changes nothing else in the program.
 
-Snapshots are plain dicts (JSON-ready); :meth:`dashboard` renders the text
-view ``tpcc_serve`` prints.
+A device trace names an operation by its HLO instruction alone, so the
+phase of an operation is read from the compiled program's text. At warm-up
+the executor :func:`register` s each jitted program it dispatches with its
+argument shapes and shardings (no compile); :func:`scope_table` lowers and
+compiles them only when a reader asks — the compile is the one already in
+JAX's cache — and maps each instruction to its phase.
 """
 
 from __future__ import annotations
 
-import contextlib
-import dataclasses
-import json
-import time
+import functools
+import re
 
 import jax
+import numpy as np
+
+# host spans (TraceMe names)
+EXEC_CALL = "exec.call"            # one FusedExecutor.run / run_escrow call
+EXEC_PREPARE = "exec.prepare"      # shard_state, ring, counters, retry ring
+EXEC_MEGASTEP = "exec.megastep"    # one megastep dispatch with its upload
+EXEC_DRAIN = "exec.drain"          # one drain dispatch
+EXEC_REFRESH = "exec.refresh"      # one drain + share refresh dispatch
+EXEC_SYNC = "exec.sync"            # the final block_until_ready
+EXEC_READBACK = "exec.readback"    # host reads of rejects and the retry ring
+EXEC_OBS_FOLD = "exec.obs_fold"    # deferred metrics-lattice folds
+AUDIT = "audit"                    # drivers.run_loop's end-of-run audit
+
+# device phases (named scopes)
+TXN_NEWORDER = "txn.neworder"      # admission, effects, the ring write
+TXN_PAYMENT = "txn.payment"
+TXN_READS = "txn.reads"            # RAMP Order-Status and Stock-Level
+TXN_DELIVERY = "txn.delivery"
+DRAIN_APPLY = "drain.apply"        # outbox drain (merge, strict, retry)
+DRAIN_REFRESH = "drain.refresh"    # escrow share refresh
+PHASES = (TXN_NEWORDER, TXN_PAYMENT, TXN_READS, TXN_DELIVERY, DRAIN_APPLY,
+          DRAIN_REFRESH)
+UNSCOPED = "unscoped"              # loop overhead, compiler-inserted copies
 
 
-@dataclasses.dataclass
-class PhaseStat:
-    count: int = 0
-    total_s: float = 0.0
-    min_s: float = float("inf")
-    max_s: float = 0.0
-
-    def record(self, dt: float) -> None:
-        self.count += 1
-        self.total_s += dt
-        self.min_s = min(self.min_s, dt)
-        self.max_s = max(self.max_s, dt)
+def span(name: str):
+    """Host span ``name`` around a ``with`` block."""
+    return jax.profiler.TraceAnnotation(name)
 
 
-class PhaseTracer:
-    """Accumulating per-phase wall clocks + JAX trace annotations."""
+def spanned(name: str):
+    """Decorator: every call of the function is host span ``name``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+        return call
+    return wrap
 
-    def __init__(self, enabled: bool = True, sync: bool = False):
-        self.enabled = enabled
-        self.sync = sync
-        self.phases: dict[str, PhaseStat] = {}
 
-    @contextlib.contextmanager
-    def span(self, phase: str):
-        if not self.enabled:
-            yield self
-            return
-        with jax.profiler.TraceAnnotation(phase):
-            t0 = time.perf_counter()
-            try:
-                yield self
-            finally:
-                self.phases.setdefault(phase, PhaseStat()).record(
-                    time.perf_counter() - t0)
+def phase(name: str):
+    """Device phase ``name`` around the operations traced in a block."""
+    return jax.named_scope(name)
 
-    def maybe_sync(self, value):
-        """Block on ``value`` iff the tracer is in sync mode — callers put
-        this at the end of a span to attribute device time to the phase."""
-        if self.enabled and self.sync:
-            jax.block_until_ready(value)
-        return value
 
-    def record(self, phase: str, seconds: float) -> None:
-        """Record an externally-timed interval (e.g. the executor's own
-        blocked wall clock)."""
-        if self.enabled:
-            self.phases.setdefault(phase, PhaseStat()).record(seconds)
+def _arg_spec(x):
+    if isinstance(x, jax.Array):
+        # an uncommitted array (jnp.asarray of a host value) follows the
+        # program's placement at dispatch; a committed one keeps its own
+        committed = getattr(x, "_committed", True)
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, weak_type=x.aval.weak_type,
+            sharding=x.sharding if committed else None)
+    x = np.asarray(x)
+    return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
-    # -- export --------------------------------------------------------------
 
-    def snapshot(self) -> dict:
-        total = sum(p.total_s for p in self.phases.values()) or 1.0
-        return {
-            "sync": self.sync,
-            "phases": {
-                name: {
-                    "count": p.count,
-                    "total_s": p.total_s,
-                    "mean_s": p.total_s / p.count if p.count else 0.0,
-                    "min_s": 0.0 if p.min_s == float("inf") else p.min_s,
-                    "max_s": p.max_s,
-                    "share": p.total_s / total,
-                }
-                for name, p in self.phases.items()
-            },
-        }
+# The programs dispatched in this process, one entry per program name (the
+# latest argument specs), and their scope table once a reader asked: the
+# benchmark's metric readers find it here after the run, with no handle on
+# the executor that dispatched.
+_PROGRAMS: dict[str, tuple] = {}
+_TABLE: dict[str, dict[str, str]] | None = None
 
-    def dashboard(self) -> str:
-        snap = self.snapshot()
-        mode = "device-synced" if self.sync else "dispatch-side"
-        lines = [f"phase breakdown ({mode} wall clocks):",
-                 f"  {'phase':<16}{'calls':>7}{'total':>11}{'mean':>11}"
-                 f"{'share':>8}"]
-        for name, p in snap["phases"].items():
-            lines.append(
-                f"  {name:<16}{p['count']:>7}{p['total_s'] * 1e3:>9.1f}ms"
-                f"{p['mean_s'] * 1e6:>9.0f}us{p['share']:>7.1%}")
-        return "\n".join(lines)
 
-    def to_json(self) -> str:
-        return json.dumps(self.snapshot(), indent=2)
+def register(fn, *args) -> None:
+    """Note jitted program ``fn`` as dispatched with ``args``; keeps their
+    shapes, dtypes and shardings, never the arrays, and compiles nothing."""
+    global _TABLE
+    _PROGRAMS[fn.__name__] = (fn, jax.tree.map(_arg_spec, args))
+    _TABLE = None
+
+
+def scope_table() -> dict[str, dict[str, str]]:
+    """``{module name: {instruction name: phase}}`` over the registered
+    programs. The phase is the first of :data:`PHASES` in the instruction's
+    ``op_name``, else :data:`UNSCOPED`. Computed on the first call after a
+    registration."""
+    global _TABLE
+    if _TABLE is None:
+        _TABLE = {}
+        for fn, specs in _PROGRAMS.values():
+            module, scopes = program_scopes(fn.lower(*specs))
+            if scopes:
+                _TABLE[module] = scopes
+    return _TABLE
+
+
+def _reset() -> None:
+    """Forget every registered program (for tests)."""
+    global _TABLE
+    _PROGRAMS.clear()
+    _TABLE = None
+
+
+_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([^\s=]+) = ")
+_SIGNATURE = re.compile(r" = (.*?) ([\w-]+)\(")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def phase_of(op_name: str) -> str:
+    for part in op_name.split("/"):
+        if part in PHASES:
+            return part
+    return UNSCOPED
+
+
+def _instructions(text: str) -> list[tuple[str, tuple, str]]:
+    """``(name, (shape, opcode), phase)`` of each instruction of an HLO
+    text, in the order printed (the schedule's, for a compiled program)."""
+    out = []
+    for line in text.splitlines():
+        i = _INSTR.match(line)
+        if i:
+            sig = _SIGNATURE.search(line)
+            o = _OP_NAME.search(line)
+            out.append((i[1], sig.groups() if sig else (),
+                        phase_of(o[1]) if o else UNSCOPED))
+    return out
+
+
+def hlo_scopes(text: str) -> tuple[str, dict[str, str]]:
+    """Module name and ``{instruction: phase}`` of a compiled HLO text."""
+    m = _MODULE.match(text)
+    return (m[1] if m else "",
+            {name: phase for name, _, phase in _instructions(text)})
+
+
+def carry_phases(ran: str, own: str) -> dict[str, str]:
+    """``{instruction of ran: phase}`` with the phases of ``own``, a compile
+    of the same program that kept its metadata, position by position; empty
+    unless every position holds the same opcode and shape in both."""
+    ran_i, own_i = _instructions(ran), _instructions(own)
+    if [sig for _, sig, _ in ran_i] != [sig for _, sig, _ in own_i]:
+        return {}
+    return {name: phase for (name, _, _), (_, _, phase) in zip(ran_i, own_i)}
+
+
+def program_scopes(lowered) -> tuple[str, dict[str, str]]:
+    """The scope map of the executable that runs a lowered program.
+
+    The persistent compilation cache keys programs with their metadata
+    stripped, so an executable compiled by a build without these scopes can
+    stand in for this one, with the same schedule but instructions numbered
+    apart. Then this build's program is compiled afresh (under a key that
+    build never wrote: the option only embeds the HLO in the executable)
+    and its phases carried over by :func:`carry_phases`."""
+    text = lowered.compile().as_text()
+    module, scopes = hlo_scopes(text)
+    if set(scopes.values()) <= {UNSCOPED} and any(
+            p in lowered.as_text(debug_info=True) for p in PHASES):
+        own = lowered.compile({"xla_embed_ir_in_executable": True}).as_text()
+        scopes = carry_phases(text, own)
+    return module, scopes

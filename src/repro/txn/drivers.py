@@ -36,7 +36,6 @@ thin signature-compatible wrappers; engine.py lazily re-exports them.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 
@@ -45,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.planner import CoordClass
+from repro.obs import trace
 
 from . import tpcc
 from .tpcc import NewOrderBatch, StockDelta, TPCCState
@@ -379,8 +379,7 @@ def run_loop(engine, state: TPCCState, esc=None, *,
 
     if audit:
         from .audit import assert_audit
-        with obs.span("audit") if obs is not None else \
-                contextlib.nullcontext():
+        with trace.span(trace.AUDIT):
             if escrow:
                 assert_audit(state, escrow=esc, initial_stock=q0,
                              strict_stock=True)

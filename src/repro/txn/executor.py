@@ -52,11 +52,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
 
-import contextlib
-
 from repro.core.lattice import EscrowCounter
 from repro.core.planner import CoordClass
 from repro.obs import metrics as obsm
+from repro.obs import trace
 from repro.utils.hlo import assert_no_collectives, collective_stats
 
 from . import ramp, tpcc
@@ -182,35 +181,39 @@ class FusedExecutor:
             Deliberately metrics-free: the obs plane records once per chunk,
             after the scan, from the chunk inputs and the counter deltas."""
             if pay_b is not None:
-                state = tpcc.apply_payment(state, pay_b, w_lo=w_lo)
-                cnt = cnt._replace(payments=cnt.payments + pay_b.w.shape[0])
-            if os_b is not None:
-                os_res = ramp.apply_order_status(state, os_b, w_lo=w_lo)
-                cnt = cnt._replace(
-                    order_statuses=cnt.order_statuses + os_b.w.shape[0],
-                    reads_found=cnt.reads_found
-                    + os_res.found.sum().astype(jnp.int32),
-                    fractures_observed=cnt.fractures_observed
-                    + os_res.fractures_observed().astype(jnp.int32),
-                    lines_repaired=cnt.lines_repaired
-                    + os_res.repaired.sum().astype(jnp.int32))
-            if sl_b is not None:
-                sl_res = ramp.apply_stock_level(state, sl_b, scale,
-                                                w_lo=w_lo)
-                cnt = cnt._replace(
-                    stock_levels=cnt.stock_levels + sl_b.w.shape[0],
-                    fractures_observed=cnt.fractures_observed
-                    + (sl_res.fractured - sl_res.repaired).sum()
-                    .astype(jnp.int32),
-                    lines_repaired=cnt.lines_repaired
-                    + sl_res.repaired.sum().astype(jnp.int32))
+                with trace.phase(trace.TXN_PAYMENT):
+                    state = tpcc.apply_payment(state, pay_b, w_lo=w_lo)
+                    cnt = cnt._replace(
+                        payments=cnt.payments + pay_b.w.shape[0])
+            with trace.phase(trace.TXN_READS):
+                if os_b is not None:
+                    os_res = ramp.apply_order_status(state, os_b, w_lo=w_lo)
+                    cnt = cnt._replace(
+                        order_statuses=cnt.order_statuses + os_b.w.shape[0],
+                        reads_found=cnt.reads_found
+                        + os_res.found.sum().astype(jnp.int32),
+                        fractures_observed=cnt.fractures_observed
+                        + os_res.fractures_observed().astype(jnp.int32),
+                        lines_repaired=cnt.lines_repaired
+                        + os_res.repaired.sum().astype(jnp.int32))
+                if sl_b is not None:
+                    sl_res = ramp.apply_stock_level(state, sl_b, scale,
+                                                    w_lo=w_lo)
+                    cnt = cnt._replace(
+                        stock_levels=cnt.stock_levels + sl_b.w.shape[0],
+                        fractures_observed=cnt.fractures_observed
+                        + (sl_res.fractured - sl_res.repaired).sum()
+                        .astype(jnp.int32),
+                        lines_repaired=cnt.lines_repaired
+                        + sl_res.repaired.sum().astype(jnp.int32))
             if self.deliveries:
-                n_del = state.no_valid.any(axis=2).sum()
-                state = tpcc.apply_delivery(
-                    state, jnp.asarray(1, jnp.int32),
-                    jnp.asarray(0, jnp.int32))
-                cnt = cnt._replace(
-                    deliveries=cnt.deliveries + n_del.astype(jnp.int32))
+                with trace.phase(trace.TXN_DELIVERY):
+                    n_del = state.no_valid.any(axis=2).sum()
+                    state = tpcc.apply_delivery(
+                        state, jnp.asarray(1, jnp.int32),
+                        jnp.asarray(0, jnp.int32))
+                    cnt = cnt._replace(
+                        deliveries=cnt.deliveries + n_del.astype(jnp.int32))
             return state, cnt
 
         def _mega_body(state, ring, counters, chunk):
@@ -227,14 +230,15 @@ class FusedExecutor:
                 state, ring, cnt = carry
                 no_b, pay_b, os_b, sl_b, i = xs
                 B = no_b.w.shape[0]
-                state, delta, _ = tpcc.apply_neworder(
-                    state, no_b, scale, w_lo=w_lo,
-                    w_hi=w_lo + eng.w_per_shard,
-                    replica=idx, num_replicas=eng.n_shards)
-                ring = OutboxRing(*(
-                    jax.lax.dynamic_update_index_in_dim(r, v, i % rows, 0)
-                    for r, v in zip(ring, delta)))
-                cnt = cnt._replace(neworders=cnt.neworders + B)
+                with trace.phase(trace.TXN_NEWORDER):
+                    state, delta, _ = tpcc.apply_neworder(
+                        state, no_b, scale, w_lo=w_lo,
+                        w_hi=w_lo + eng.w_per_shard,
+                        replica=idx, num_replicas=eng.n_shards)
+                    ring = OutboxRing(*(
+                        jax.lax.dynamic_update_index_in_dim(r, v, i % rows, 0)
+                        for r, v in zip(ring, delta)))
+                    cnt = cnt._replace(neworders=cnt.neworders + B)
                 state, cnt = step_tail(state, cnt, pay_b, os_b, sl_b, w_lo)
                 return (state, ring, cnt), None
 
@@ -261,27 +265,32 @@ class FusedExecutor:
                 state, ring, cnt, esc = carry
                 no_b, pay_b, os_b, sl_b, i = xs
                 B = no_b.w.shape[0]
-                if self._sparse:
-                    state, spent, delta, _, ok = \
-                        tpcc.apply_neworder_escrow_sparse(
-                            state, esc.keys, esc.shares[0], esc.spent[0],
-                            no_b, scale, w_lo=w_lo,
-                            w_hi=w_lo + eng.w_per_shard,
-                            replica=idx, num_replicas=eng.n_shards,
-                            admission=eng.admission, effects=eng.effects)
-                else:
-                    state, spent, delta, _, ok = tpcc.apply_neworder_escrow(
-                        state, esc.shares[0], esc.spent[0], no_b, scale,
-                        w_lo=w_lo, w_hi=w_lo + eng.w_per_shard,
-                        replica=idx, num_replicas=eng.n_shards,
-                        admission=eng.admission, effects=eng.effects)
-                esc = esc._replace(spent=spent[None])
-                ring = OutboxRing(*(
-                    jax.lax.dynamic_update_index_in_dim(r, v, i % rows, 0)
-                    for r, v in zip(ring, delta)))
-                n_ok = ok.sum().astype(jnp.int32)
-                cnt = cnt._replace(neworders=cnt.neworders + n_ok,
-                                   aborts=cnt.aborts + (B - n_ok))
+                with trace.phase(trace.TXN_NEWORDER):
+                    if self._sparse:
+                        state, spent, delta, _, ok = \
+                            tpcc.apply_neworder_escrow_sparse(
+                                state, esc.keys, esc.shares[0],
+                                esc.spent[0], no_b, scale, w_lo=w_lo,
+                                w_hi=w_lo + eng.w_per_shard,
+                                replica=idx, num_replicas=eng.n_shards,
+                                admission=eng.admission,
+                                effects=eng.effects)
+                    else:
+                        state, spent, delta, _, ok = \
+                            tpcc.apply_neworder_escrow(
+                                state, esc.shares[0], esc.spent[0], no_b,
+                                scale, w_lo=w_lo,
+                                w_hi=w_lo + eng.w_per_shard,
+                                replica=idx, num_replicas=eng.n_shards,
+                                admission=eng.admission,
+                                effects=eng.effects)
+                    esc = esc._replace(spent=spent[None])
+                    ring = OutboxRing(*(
+                        jax.lax.dynamic_update_index_in_dim(r, v, i % rows, 0)
+                        for r, v in zip(ring, delta)))
+                    n_ok = ok.sum().astype(jnp.int32)
+                    cnt = cnt._replace(neworders=cnt.neworders + n_ok,
+                                       aborts=cnt.aborts + (B - n_ok))
                 state, cnt = step_tail(state, cnt, pay_b, os_b, sl_b, w_lo)
                 return (state, ring, cnt, esc), (ok if want_ok else None)
 
@@ -364,8 +373,9 @@ class FusedExecutor:
             # (all queued outboxes at once) and apply the entries we own —
             # the same body Engine.anti_entropy runs per outbox
             w_lo = eng._shard_index() * eng.w_per_shard
-            state = gather_and_apply_outbox(state, ring, ax, w_lo,
-                                            eng.w_per_shard, restock=True)
+            with trace.phase(trace.DRAIN_APPLY):
+                state = gather_and_apply_outbox(state, ring, ax, w_lo,
+                                                eng.w_per_shard, restock=True)
             return state, ring._replace(valid=jnp.zeros_like(ring.valid))
 
         def _strict_drain_body(state, ring, hot_keys, w_lo):
@@ -373,13 +383,15 @@ class FusedExecutor:
             # unconditionally (share-admitted), cold entries under the
             # owner's per-cell all-or-nothing admission (sparse layout);
             # dense has no cold tier, so rejects are structurally zero
-            if self._sparse:
-                return gather_and_apply_outbox_strict(
-                    state, ring, hot_keys, ax, w_lo, eng.w_per_shard,
-                    scale.n_items)
-            state = gather_and_apply_outbox(state, ring, ax, w_lo,
-                                            eng.w_per_shard, restock=False)
-            return state, jnp.zeros((1,), jnp.int32)
+            with trace.phase(trace.DRAIN_APPLY):
+                if self._sparse:
+                    return gather_and_apply_outbox_strict(
+                        state, ring, hot_keys, ax, w_lo, eng.w_per_shard,
+                        scale.n_items)
+                state = gather_and_apply_outbox(state, ring, ax, w_lo,
+                                                eng.w_per_shard,
+                                                restock=False)
+                return state, jnp.zeros((1,), jnp.int32)
 
         @functools.partial(
             shard_map, mesh=eng.mesh,
@@ -409,13 +421,14 @@ class FusedExecutor:
             w_lo = idx * eng.w_per_shard
             hot_keys = esc.keys if self._sparse else None
             state, rej = _strict_drain_body(state, ring, hot_keys, w_lo)
-            if self._sparse:
-                esc = gather_and_refresh_hot_shares(
-                    state, esc.keys, ax, idx, eng.n_shards, scale.n_items,
-                    w_lo, eng.w_per_shard, alive=alive)
-            else:
-                esc = gather_and_refresh_shares(state, ax, idx, eng.n_shards,
-                                                alive=alive)
+            with trace.phase(trace.DRAIN_REFRESH):
+                if self._sparse:
+                    esc = gather_and_refresh_hot_shares(
+                        state, esc.keys, ax, idx, eng.n_shards,
+                        scale.n_items, w_lo, eng.w_per_shard, alive=alive)
+                else:
+                    esc = gather_and_refresh_shares(
+                        state, ax, idx, eng.n_shards, alive=alive)
             return state, ring._replace(
                 valid=jnp.zeros_like(ring.valid)), esc, rej
 
@@ -436,9 +449,10 @@ class FusedExecutor:
             # retry_max windows; reserve > 0 grants last-chance losers an
             # owner reservation (sparse-only; built when retry_cap > 0)
             w_lo = eng._shard_index() * eng.w_per_shard
-            state, retry, rej = gather_and_apply_outbox_strict_retry(
-                state, ring, retry, eng.hot_keys, ax, w_lo, eng.w_per_shard,
-                scale.n_items, retry_max, reserve)
+            with trace.phase(trace.DRAIN_APPLY):
+                state, retry, rej = gather_and_apply_outbox_strict_retry(
+                    state, ring, retry, eng.hot_keys, ax, w_lo,
+                    eng.w_per_shard, scale.n_items, retry_max, reserve)
             return state, ring._replace(
                 valid=jnp.zeros_like(ring.valid)), retry, rej
 
@@ -457,12 +471,14 @@ class FusedExecutor:
             # collective program per refresh boundary
             idx = eng._shard_index()
             w_lo = idx * eng.w_per_shard
-            state, retry, rej = gather_and_apply_outbox_strict_retry(
-                state, ring, retry, eng.hot_keys, ax, w_lo, eng.w_per_shard,
-                scale.n_items, retry_max, reserve)
-            esc = gather_and_refresh_hot_shares(
-                state, esc.keys, ax, idx, eng.n_shards, scale.n_items,
-                w_lo, eng.w_per_shard, alive=alive)
+            with trace.phase(trace.DRAIN_APPLY):
+                state, retry, rej = gather_and_apply_outbox_strict_retry(
+                    state, ring, retry, eng.hot_keys, ax, w_lo,
+                    eng.w_per_shard, scale.n_items, retry_max, reserve)
+            with trace.phase(trace.DRAIN_REFRESH):
+                esc = gather_and_refresh_hot_shares(
+                    state, esc.keys, ax, idx, eng.n_shards, scale.n_items,
+                    w_lo, eng.w_per_shard, alive=alive)
             return state, ring._replace(
                 valid=jnp.zeros_like(ring.valid)), retry, esc, rej
 
@@ -544,16 +560,18 @@ class FusedExecutor:
         at the owner). Returns (state, ring, per-shard cold rejects)."""
         return self._drain_strict(state, ring)
 
+    def _alive(self, alive) -> Array:
+        """The refresh's ``[n_shards]`` liveness mask (default all-live)."""
+        return jnp.asarray(self.engine._alive_all if alive is None else alive,
+                           jnp.int32)
+
     def drain_refresh(self, state: TPCCState, ring: OutboxRing, esc,
                       alive=None):
         """Strict drain + escrow share refresh fused into one collective
         program. Returns (state, ring, esc, per-shard cold rejects).
         ``alive`` ([n_shards] mask, default all-live) reclaims dead
         replicas' share headroom for the survivors."""
-        if alive is None:
-            alive = self.engine._alive_all
-        return self._drain_refresh(state, ring, esc,
-                                   jnp.asarray(alive, jnp.int32))
+        return self._drain_refresh(state, ring, esc, self._alive(alive))
 
     def init_retry(self):
         """Per-owner retry ring buffers ([n_shards, retry_cap])."""
@@ -576,13 +594,12 @@ class FusedExecutor:
         """Retry-aware drain + reclaiming share refresh (one collective
         program). Returns (state, ring, retry', esc, per-shard final
         rejects)."""
-        if alive is None:
-            alive = self.engine._alive_all
         return self._drain_refresh_retry(state, ring, retry, esc,
-                                         jnp.asarray(alive, jnp.int32),
+                                         self._alive(alive),
                                          jnp.asarray(retry_max, jnp.int32),
                                          jnp.asarray(reserve, jnp.int32))
 
+    @trace.spanned(trace.EXEC_CALL)
     def run(self, state: TPCCState, chunks: Sequence[MixChunk],
             *, warmup: bool = True, obs=None
             ) -> tuple[TPCCState, MixCounters, float]:
@@ -591,31 +608,32 @@ class FusedExecutor:
         excludes compilation (triggered on throwaway copies) and batch prep.
 
         ``obs`` (an ``repro.obs.ObsSession``) keeps the on-device metrics
-        lattice fed beside the run (when the session wants metrics) and
-        wraps each phase in a tracer span. The hot megastep is the SAME
-        compiled program with metrics on or off, and the timed loop makes
-        zero extra dispatches: because lattice joins are commutative and
-        associative, the per-chunk ``_record`` folds run after the wall
-        clock stops (bit-identical to inline recording), followed by one
-        ``_fold_counters``, landing in ``obs.device_metrics`` — zero host
-        transfers, zero collectives.
+        lattice fed beside the run (when the session wants metrics). The
+        hot megastep is the SAME compiled program with metrics on or off,
+        and the timed loop makes zero extra dispatches: because lattice
+        joins are commutative and associative, the per-chunk ``_record``
+        folds run after the wall clock stops (bit-identical to inline
+        recording), followed by one ``_fold_counters``, landing in
+        ``obs.device_metrics`` — zero host transfers, zero collectives.
+        Every phase of the call is a host span of ``repro.obs.trace``.
         """
         if self._escrow:
             raise RuntimeError("escrow-regime executor: use run_escrow")
         batch_per_shard = chunks[0].neworder.w.shape[1] // self.engine.n_shards
-        state = self.engine.shard_state(state)  # commit: stable jit cache key
-        ring = self.init_ring(batch_per_shard)
-        counters = self.init_counters()
-        metrics = obs.init_metrics(self.engine) if obs is not None and \
-            obs.wants_metrics else None
-        span = obs.span if obs is not None else \
-            (lambda name: contextlib.nullcontext())
+        with trace.span(trace.EXEC_PREPARE):
+            state = self.engine.shard_state(state)  # commit: stable jit key
+            ring = self.init_ring(batch_per_shard)
+            counters = self.init_counters()
+            metrics = obs.init_metrics(self.engine) if obs is not None and \
+                obs.wants_metrics else None
         if warmup:
             copy = lambda t: jax.tree.map(lambda x: x.copy(), t)
             for T in sorted({c.chunk_len for c in chunks}):
                 chunk = next(c for c in chunks if c.chunk_len == T)
-                w = self.megastep(copy(state), copy(ring),
-                                  copy(counters), chunk)
+                trace.register(self._megastep, state, ring, counters, chunk)
+                w = self.megastep(copy(state), copy(ring), copy(counters),
+                                  chunk)
+                trace.register(self._drain, w[0], w[1])
                 jax.block_until_ready(self.drain(w[0], w[1]))
                 if metrics is not None:
                     jax.block_until_ready(
@@ -626,16 +644,13 @@ class FusedExecutor:
 
         t0 = time.perf_counter()
         for chunk in chunks:
-            with span("megastep"):
+            with trace.span(trace.EXEC_MEGASTEP):
                 state, ring, counters = self.megastep(state, ring,
                                                       counters, chunk)
-                if obs is not None:
-                    obs.maybe_sync(counters)
-            with span("outbox-drain"):
+            with trace.span(trace.EXEC_DRAIN):
                 state, ring = self.drain(state, ring)
-                if obs is not None:
-                    obs.maybe_sync(ring)
-        jax.block_until_ready((state, counters))
+        with trace.span(trace.EXEC_SYNC):
+            jax.block_until_ready((state, counters))
         wall = time.perf_counter() - t0
         if metrics is not None:
             # deferred lattice folds: every record is a commutative join of
@@ -643,11 +658,13 @@ class FusedExecutor:
             # bit-identical to folding inline — and the hot loop pays zero
             # extra dispatches (dispatch wall time is the one real cost of
             # an extra per-chunk program on this backend)
-            for chunk in chunks:
-                metrics = self._record(metrics, chunk.neworder)
-            obs.device_metrics = self._fold_counters(metrics, counters)
+            with trace.span(trace.EXEC_OBS_FOLD):
+                for chunk in chunks:
+                    metrics = self._record(metrics, chunk.neworder)
+                obs.device_metrics = self._fold_counters(metrics, counters)
         return state, counters, wall
 
+    @trace.spanned(trace.EXEC_CALL)
     def run_escrow(self, state: TPCCState, esc, chunks: Sequence[MixChunk],
                    *, refresh_every: int = 1,
                    refresh_abort_rate: float | None = None,
@@ -675,50 +692,25 @@ class FusedExecutor:
         monitor ticks once per chunk (one drain window) and its
         lease-expiry view feeds every refresh, so no caller-provided mask
         is needed. ``reserve`` > 0 (traced — same compiled drain) enables
-        the cold-line reservation round-trip. Returns (state, esc,
-        counters, wall_seconds, refreshes, cold_rejects, retry)."""
+        the cold-line reservation round-trip. Every phase of the call is a
+        host span of ``repro.obs.trace``. Returns (state, esc, counters,
+        wall_seconds, refreshes, cold_rejects, retry)."""
         if not self._escrow:
             raise RuntimeError("executor is not in the escrow regime "
                                "(engine plan says merge) — use run()")
         use_retry = self.retry_cap > 0
-        if use_retry and retry is None:
-            retry = self.init_retry()
         batch_per_shard = chunks[0].neworder.w.shape[1] // self.engine.n_shards
-        state = self.engine.shard_state(state)
-        ring = self.init_ring(batch_per_shard)
-        counters = self.init_counters()
-        metrics = obs.init_metrics(self.engine) if obs is not None and \
-            obs.wants_metrics else None
-        span = obs.span if obs is not None else \
-            (lambda name: contextlib.nullcontext())
+        with trace.span(trace.EXEC_PREPARE):
+            if use_retry and retry is None:
+                retry = self.init_retry()
+            state = self.engine.shard_state(state)
+            ring = self.init_ring(batch_per_shard)
+            counters = self.init_counters()
+            metrics = obs.init_metrics(self.engine) if obs is not None and \
+                obs.wants_metrics else None
         if warmup:
-            copy = lambda t: jax.tree.map(lambda x: x.copy(), t)
-            for T in sorted({c.chunk_len for c in chunks}):
-                chunk = next(c for c in chunks if c.chunk_len == T)
-                if metrics is not None:
-                    w = self._megastep_esc_obs(
-                        copy(state), copy(ring), copy(counters), copy(esc),
-                        chunk)
-                    jax.block_until_ready(
-                        self._record_esc(copy(metrics), chunk.neworder,
-                                         w[4]))
-                    w = w[:4]
-                else:
-                    w = self.megastep_escrow(copy(state), copy(ring),
-                                             copy(counters), copy(esc),
-                                             chunk)
-                if use_retry:
-                    w2 = self.drain_refresh_retry(w[0], w[1], copy(retry),
-                                                  w[3], alive, retry_max,
-                                                  reserve)
-                    jax.block_until_ready(self.drain_strict_retry(
-                        w2[0], w2[1], w2[2], retry_max, reserve))
-                else:
-                    w2 = self.drain_refresh(w[0], w[1], w[3], alive)
-                    jax.block_until_ready(self.drain_strict(w2[0], w2[1]))
-            if metrics is not None:
-                jax.block_until_ready(
-                    self._fold_counters(copy(metrics), counters))
+            self._warm_escrow(state, ring, counters, esc, chunks, metrics,
+                              retry, retry_max, alive, reserve)
 
         adaptive = refresh_abort_rate is not None
         aborts_at_refresh = np.zeros(self.engine.n_shards, np.int64)
@@ -729,7 +721,7 @@ class FusedExecutor:
         oks = []
         t0 = time.perf_counter()
         for ci, chunk in enumerate(chunks):
-            with span("megastep"):
+            with trace.span(trace.EXEC_MEGASTEP):
                 if metrics is not None:
                     # the commit masks are already megastep outputs —
                     # keeping the handles costs the loop nothing, and the
@@ -742,13 +734,12 @@ class FusedExecutor:
                 else:
                     state, ring, counters, esc = self.megastep_escrow(
                         state, ring, counters, esc, chunk)
-                if obs is not None:
-                    obs.maybe_sync(counters)
             if adaptive:
                 from .drivers import _adaptive_refresh_due
                 # per-replica abort rate since the last refresh — one small
                 # counter transfer per chunk
-                ab = np.asarray(jax.device_get(counters.aborts), np.int64)
+                with trace.span(trace.EXEC_READBACK):
+                    ab = np.asarray(jax.device_get(counters.aborts), np.int64)
                 txns_so_far += chunk.chunk_len * batch_per_shard
                 due = _adaptive_refresh_due(ab - aborts_at_refresh,
                                             txns_so_far - txns_at_refresh,
@@ -765,7 +756,7 @@ class FusedExecutor:
                 # lease-expiry mask feeds the next share refresh
                 alive = liveness.tick().astype(np.int32)
             if due:
-                with span("share-refresh"):
+                with trace.span(trace.EXEC_REFRESH):
                     if use_retry:
                         state, ring, retry, esc, rej = \
                             self.drain_refresh_retry(state, ring, retry,
@@ -774,36 +765,78 @@ class FusedExecutor:
                     else:
                         state, ring, esc, rej = self.drain_refresh(
                             state, ring, esc, alive)
-                    if obs is not None:
-                        obs.maybe_sync(esc)
                 refreshes += 1
             else:
-                with span("outbox-drain"):
+                with trace.span(trace.EXEC_DRAIN):
                     if use_retry:
                         state, ring, retry, rej = self.drain_strict_retry(
                             state, ring, retry, retry_max, reserve)
                     else:
                         state, ring, rej = self.drain_strict(state, ring)
-                    if obs is not None:
-                        obs.maybe_sync(ring)
             rejs.append(rej)
-        jax.block_until_ready((state, esc, counters))
+        with trace.span(trace.EXEC_SYNC):
+            jax.block_until_ready((state, esc, counters))
         wall = time.perf_counter() - t0
         if metrics is not None:
             # deferred lattice folds (joins commute — bit-identical to
             # inline recording, zero dispatches inside the timed loop)
-            for chunk, ok in zip(chunks, oks):
-                metrics = self._record_esc(metrics, chunk.neworder, ok)
-            for rej in rejs:
-                metrics = obsm.add_cold_rejects(metrics, rej)
-            obs.device_metrics = self._fold_counters(metrics, counters)
-        cold = int(np.asarray(jax.device_get(rejs)).sum()) if rejs else 0
-        if use_retry and final_flush:
-            # entries still pending in the ring when the run ends never got
-            # their retry_max-th window — surface them as final rejects so
-            # optimistic admits == applied + cold_rejects holds exactly
-            cold += int(np.asarray(jax.device_get(retry.valid)).sum())
+            with trace.span(trace.EXEC_OBS_FOLD):
+                for chunk, ok in zip(chunks, oks):
+                    metrics = self._record_esc(metrics, chunk.neworder, ok)
+                for rej in rejs:
+                    metrics = obsm.add_cold_rejects(metrics, rej)
+                obs.device_metrics = self._fold_counters(metrics, counters)
+        with trace.span(trace.EXEC_READBACK):
+            cold = int(np.asarray(jax.device_get(rejs)).sum()) if rejs else 0
+            if use_retry and final_flush:
+                # entries still pending in the ring when the run ends never
+                # got their retry_max-th window — surface them as final
+                # rejects so optimistic admits == applied + cold_rejects
+                # holds exactly
+                cold += int(np.asarray(jax.device_get(retry.valid)).sum())
         return state, esc, counters, wall, refreshes, cold, retry
+
+    def _warm_escrow(self, state, ring, counters, esc, chunks, metrics,
+                     retry, retry_max, alive, reserve):
+        """Compile every program the escrow loop dispatches, on copies, and
+        register each with ``repro.obs.trace``."""
+        copy = lambda t: jax.tree.map(lambda x: x.copy(), t)
+        i32 = lambda v: jnp.asarray(v, jnp.int32)
+        for T in sorted({c.chunk_len for c in chunks}):
+            chunk = next(c for c in chunks if c.chunk_len == T)
+            if metrics is not None:
+                trace.register(self._megastep_esc_obs, state, ring, counters,
+                               esc, chunk)
+                w = self._megastep_esc_obs(
+                    copy(state), copy(ring), copy(counters), copy(esc),
+                    chunk)
+                jax.block_until_ready(
+                    self._record_esc(copy(metrics), chunk.neworder, w[4]))
+                w = w[:4]
+            else:
+                trace.register(self._megastep_esc, state, ring, counters,
+                               esc, chunk)
+                w = self.megastep_escrow(copy(state), copy(ring),
+                                         copy(counters), copy(esc), chunk)
+            if self.retry_cap > 0:
+                trace.register(self._drain_refresh_retry, w[0], w[1], retry,
+                               w[3], self._alive(alive), i32(retry_max),
+                               i32(reserve))
+                w2 = self.drain_refresh_retry(w[0], w[1], copy(retry), w[3],
+                                              alive, retry_max, reserve)
+                trace.register(self._drain_strict_retry, w2[0], w2[1], w2[2],
+                               i32(retry_max), i32(reserve))
+                jax.block_until_ready(self.drain_strict_retry(
+                    w2[0], w2[1], w2[2], retry_max, reserve))
+            else:
+                trace.register(self._drain_refresh, w[0], w[1], w[3],
+                               self._alive(alive))
+                w2 = self.drain_refresh(w[0], w[1], w[3], alive)
+                trace.register(self._drain_strict, w2[0], w2[1])
+                jax.block_until_ready(self.drain_strict(w2[0], w2[1]))
+        if metrics is not None:
+            jax.block_until_ready(
+                self._fold_counters(copy(metrics), counters))
 
     # -- structural proofs ---------------------------------------------------
 

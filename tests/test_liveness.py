@@ -569,7 +569,7 @@ def test_obs_session_reports_detection_latency():
                                    heartbeat_lag_summary)
     from repro.core.lattice import HistogramLattice
 
-    sess = ObsSession(metrics=False, trace=False)
+    sess = ObsSession(metrics=False)
     sess.record_heartbeat_lags([3, 3, 4])
     sess.record_heartbeat_lags([2])
     snap = sess.snapshot()

@@ -19,7 +19,6 @@ import pytest
 from repro.core import lattice as lat
 from repro.obs import ObsSession, metrics as obsm
 from repro.obs.ledger import CoordinationLedger, build_ledger
-from repro.obs.trace import PhaseTracer
 from repro.txn.drivers import run_loop
 from repro.txn.engine import single_host_engine
 from repro.txn.tpcc import TPCCScale, init_state
@@ -245,7 +244,7 @@ def _run_pair(stock_invariant):
         return eng.shard_state(base)
 
     s_off, _, st_off = run_loop(eng, fresh(), **_RUN_KW)
-    obs = ObsSession(metrics=True, trace=True)
+    obs = ObsSession(metrics=True)
     s_on, _, st_on = run_loop(eng, fresh(), obs=obs, **_RUN_KW)
     return eng, (s_off, st_off), (s_on, st_on), obs
 
@@ -264,7 +263,6 @@ def test_metrics_on_is_bit_exact_merge_regime():
     assert snap["latency"]["delivery"]["count"] == st_on.deliveries
     assert sum(snap["counters"]["aborts_per_replica"]) == 0
     assert snap["item_access"]["total_line_demand"] > 0
-    assert snap["spans"]["phases"]  # the tracer saw the loop's phases
 
 
 @pytest.mark.slow
@@ -320,29 +318,3 @@ def test_build_ledger_hot_phases_are_collective_free():
     assert phases["metrics record"]["hot"]
     assert phases["metrics record"]["collectives"] == {}
     assert phases["metrics counter fold"]["collectives"] == {}
-
-
-# ---------------------------------------------------------------------------
-# Phase tracer
-# ---------------------------------------------------------------------------
-
-
-def test_tracer_span_accounting():
-    tr = PhaseTracer(enabled=True)
-    for _ in range(3):
-        with tr.span("megastep"):
-            pass
-    with tr.span("drain"):
-        pass
-    snap = tr.snapshot()
-    assert snap["phases"]["megastep"]["count"] == 3
-    assert snap["phases"]["drain"]["count"] == 1
-    shares = [p["share"] for p in snap["phases"].values()]
-    assert sum(shares) == pytest.approx(1.0)
-
-
-def test_tracer_disabled_is_inert():
-    tr = PhaseTracer(enabled=False)
-    with tr.span("megastep"):
-        pass
-    assert tr.snapshot()["phases"] == {}
