@@ -29,7 +29,10 @@ import numpy as np
 
 # host spans (TraceMe names)
 EXEC_CALL = "exec.call"            # one FusedExecutor.run / run_escrow call
-EXEC_PREPARE = "exec.prepare"      # shard_state, ring, counters, retry ring
+# a call's opening: the state leaves not yet on the run sharding are put
+# there, the ring and counters come from one program (the counts of both
+# are the span's metadata), then the retry ring and metrics lattice if used
+EXEC_PREPARE = "exec.prepare"
 EXEC_MEGASTEP = "exec.megastep"    # one megastep dispatch with its upload
 EXEC_DRAIN = "exec.drain"          # one drain dispatch
 EXEC_REFRESH = "exec.refresh"      # one drain + share refresh dispatch

@@ -359,8 +359,25 @@ class Engine:
         return idx
 
     def shard_state(self, state: TPCCState) -> TPCCState:
+        return self.place_state(state)[0]
+
+    def place_state(self, state: TPCCState) -> tuple[TPCCState, int]:
+        """``state`` on the run sharding, and how many leaves had to move.
+
+        A leaf already committed to that placement passes through untouched,
+        also where its sharding is spelled otherwise: a program's outputs on
+        a one-device mesh say ``P()`` for ``P("data")``, and a put would
+        re-wrap every table. The rest go in one batched put."""
         sharding = NamedSharding(self.mesh, self.state_spec)
-        return jax.tree.map(lambda x: jax.device_put(x, sharding), state)
+        leaves, tree = jax.tree.flatten(state)
+        move = [i for i, x in enumerate(leaves)
+                if not (getattr(x, "_committed", False)
+                        and x.sharding.is_equivalent_to(sharding, x.ndim))]
+        if move:
+            put = jax.device_put([leaves[i] for i in move], sharding)
+            for i, x in zip(move, put):
+                leaves[i] = x
+        return jax.tree.unflatten(tree, leaves), len(move)
 
     # -- public API -----------------------------------------------------------
 

@@ -91,8 +91,11 @@ class OutboxRing(NamedTuple):
 
 class MixCounters(NamedTuple):
     """On-device MixStats accumulators, one lane per shard ([n_shards] int32
-    globally, [1] per shard inside the megastep). Transferred to the host
-    exactly once, after the run's final ``block_until_ready``."""
+    globally, [1] per shard inside the megastep). Each call starts from
+    zeros made with its ring by one compiled program
+    (:meth:`FusedExecutor.init_buffers`), a buffer per field, and they are
+    transferred to the host exactly once, after the call's final
+    ``block_until_ready``."""
 
     neworders: Array
     payments: Array
@@ -103,6 +106,15 @@ class MixCounters(NamedTuple):
     fractures_observed: Array
     lines_repaired: Array
     aborts: Array   # escrow regime: insufficient-share atomic aborts
+
+
+class Prepared(NamedTuple):
+    """What a call's ``exec.prepare`` did: the state leaves it had to move
+    onto the run sharding (0 once the state is a previous call's output)
+    and the buffer programs it dispatched (the ring and counters: 1)."""
+
+    moved_leaves: int
+    buffer_programs: int
 
 
 class MixChunk(NamedTuple):
@@ -507,28 +519,79 @@ class FusedExecutor:
             self._drain_refresh_retry = jax.jit(_drain_refresh_retry,
                                                 donate_argnums=(0, 1, 2, 3))
 
+        # the zero-buffer programs, one per (ring_rows, R); what the last
+        # run / run_escrow call's prepare did
+        self._zero_programs: dict[tuple, object] = {}
+        self.last_prepare: Prepared | None = None
+
     # -- device buffers ------------------------------------------------------
 
+    def _zero_program(self, R: int | None):
+        """The jitted program that makes a call's zeroed ring ([ring_rows,
+        R] per field, sharded on dim 1) and counters ([n_shards] int32 per
+        field, sharded on dim 0), or the counters alone where ``R`` is None.
+
+        One dispatch with no host transfer. Its output shardings are the
+        run's, committed, so the megastep's jit key is the one its own
+        outputs give when they loop back. Each field is an output of its
+        own: XLA gives every output a buffer of its own, and donation must
+        not alias two arguments."""
+        key = (self.ring_rows, R)
+        fn = self._zero_programs.get(key)
+        if fn is None:
+            mesh, ax = self.engine.mesh, self.engine.axis_names
+            ring_sh = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec(None, ax))
+            count_sh = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec(ax))
+            rows, n = self.ring_rows, self.engine.n_shards
+
+            def _zero_buffers():
+                counters = MixCounters(*(jnp.zeros((n,), jnp.int32)
+                                         for _ in MixCounters._fields))
+                if R is None:
+                    return None, counters
+                z = lambda dt: jnp.zeros((rows, R), dt)
+                return OutboxRing(z(jnp.int32), z(jnp.int32), z(jnp.int32),
+                                  z(jnp.bool_)), counters
+
+            ring_out = None if R is None else OutboxRing(
+                *([ring_sh] * len(OutboxRing._fields)))
+            fn = self._zero_programs[key] = jax.jit(
+                _zero_buffers, out_shardings=(ring_out, MixCounters(
+                    *([count_sh] * len(MixCounters._fields)))))
+        return fn
+
+    def _ring_width(self, batch_per_shard: int) -> int:
+        """R: the ring's entries per row, B * L over all shards."""
+        eng = self.engine
+        return batch_per_shard * eng.n_shards * eng.scale.max_lines
+
+    def init_buffers(self, batch_per_shard: int
+                     ) -> tuple[OutboxRing, MixCounters]:
+        """A call's zeroed ring and counters, from one compiled program."""
+        return self._zero_program(self._ring_width(batch_per_shard))()
+
     def init_ring(self, batch_per_shard: int) -> OutboxRing:
-        # committed to the run sharding up front: the jit cache keys on input
-        # shardings, so uncommitted first-call buffers would force a second
-        # compile once the megastep's (committed) outputs loop back in
-        sh = jax.sharding.NamedSharding(
-            self.engine.mesh, jax.sharding.PartitionSpec(
-                None, self.engine.axis_names))
-        R = batch_per_shard * self.engine.n_shards * self.engine.scale.max_lines
-        z = lambda dt: jax.device_put(jnp.zeros((self.ring_rows, R), dt), sh)
-        return OutboxRing(z(jnp.int32), z(jnp.int32), z(jnp.int32),
-                          z(jnp.bool_))
+        """An empty outbox ring on the run sharding (see
+        :meth:`_zero_program`)."""
+        return self.init_buffers(batch_per_shard)[0]
 
     def init_counters(self) -> MixCounters:
-        sh = jax.sharding.NamedSharding(
-            self.engine.mesh, jax.sharding.PartitionSpec(
-                self.engine.axis_names))
-        # distinct buffers per field: donation must not alias two arguments
-        return MixCounters(*(
-            jax.device_put(jnp.zeros((self.engine.n_shards,), jnp.int32), sh)
-            for _ in MixCounters._fields))
+        """Zeroed counters on the run sharding (see :meth:`_zero_program`)."""
+        return self._zero_program(None)()[1]
+
+    def _prepare(self, span, state: TPCCState, batch_per_shard: int):
+        """A call's state, ring and counters, inside its ``exec.prepare``
+        ``span``: state leaves not yet on the run sharding are placed (state
+        already there, as a previous call's output is, passes through
+        untouched), and the ring and counters come from one program. The
+        counts go on the span and into :attr:`last_prepare`."""
+        state, moved = self.engine.place_state(state)
+        ring, counters = self.init_buffers(batch_per_shard)
+        self.last_prepare = Prepared(moved_leaves=moved, buffer_programs=1)
+        span.set_metadata(**self.last_prepare._asdict())
+        return state, ring, counters
 
     # -- execution -----------------------------------------------------------
 
@@ -620,10 +683,9 @@ class FusedExecutor:
         if self._escrow:
             raise RuntimeError("escrow-regime executor: use run_escrow")
         batch_per_shard = chunks[0].neworder.w.shape[1] // self.engine.n_shards
-        with trace.span(trace.EXEC_PREPARE):
-            state = self.engine.shard_state(state)  # commit: stable jit key
-            ring = self.init_ring(batch_per_shard)
-            counters = self.init_counters()
+        with trace.span(trace.EXEC_PREPARE) as span:
+            state, ring, counters = self._prepare(span, state,
+                                                  batch_per_shard)
             metrics = obs.init_metrics(self.engine) if obs is not None and \
                 obs.wants_metrics else None
         if warmup:
@@ -700,12 +762,11 @@ class FusedExecutor:
                                "(engine plan says merge) — use run()")
         use_retry = self.retry_cap > 0
         batch_per_shard = chunks[0].neworder.w.shape[1] // self.engine.n_shards
-        with trace.span(trace.EXEC_PREPARE):
+        with trace.span(trace.EXEC_PREPARE) as span:
             if use_retry and retry is None:
                 retry = self.init_retry()
-            state = self.engine.shard_state(state)
-            ring = self.init_ring(batch_per_shard)
-            counters = self.init_counters()
+            state, ring, counters = self._prepare(span, state,
+                                                  batch_per_shard)
             metrics = obs.init_metrics(self.engine) if obs is not None and \
                 obs.wants_metrics else None
         if warmup:
@@ -841,7 +902,7 @@ class FusedExecutor:
     # -- structural proofs ---------------------------------------------------
 
     def _ring_specs(self, batch_per_shard: int) -> OutboxRing:
-        R = batch_per_shard * self.engine.n_shards * self.engine.scale.max_lines
+        R = self._ring_width(batch_per_shard)
         f = jax.ShapeDtypeStruct
         return OutboxRing(f((self.ring_rows, R), jnp.int32),
                           f((self.ring_rows, R), jnp.int32),

@@ -6,6 +6,10 @@
   phase and of the fused drain to ``drain.apply`` and ``drain.refresh``;
 * registering programs at warm-up compiles nothing, and neither does the
   scope table of programs already dispatched;
+* a call on the previous call's output compiles nothing and its
+  ``exec.prepare`` moves no state leaf and dispatches one buffer program,
+  by the executor's count and by the span's metadata; a call on host state
+  places every leaf;
 * an executable that the persistent cache serves from a build without the
   scopes (the cache key strips metadata) does not blank the table, and
   phases are carried over to it only where every position matches;
@@ -20,8 +24,8 @@ from bench import spans as bench_spans
 from bench.trace import load_xplane
 from repro.obs import trace
 from repro.txn.engine import generate_mix_batches, single_host_engine
-from repro.txn.executor import FusedExecutor, stack_chunks
-from repro.txn.tpcc import TPCCScale, init_state
+from repro.txn.executor import FusedExecutor, Prepared, stack_chunks
+from repro.txn.tpcc import TPCCScale, TPCCState, init_state
 
 SCALE = TPCCScale(n_warehouses=4, districts=4, customers=8, n_items=64,
                   order_capacity=128, max_lines=15)
@@ -55,15 +59,19 @@ def compiles():
     return Compiles()
 
 
+def _host_state(stock_invariant):
+    state = init_state(SCALE)
+    if stock_invariant == "strict":
+        state = state._replace(s_quantity=state.s_quantity * 20)
+    return state
+
+
 def _setup(stock_invariant):
     eng = single_host_engine(SCALE, stock_invariant=stock_invariant)
     ex = FusedExecutor(eng, ring_rows=4)
     chunks = stack_chunks(*generate_mix_batches(
         eng, batch_per_shard=8, n_batches=4 * N_CHUNKS, seed=5), 4)
-    state = init_state(SCALE)
-    if stock_invariant == "strict":
-        state = state._replace(s_quantity=state.s_quantity * 20)
-    return eng, ex, chunks, eng.shard_state(state)
+    return eng, ex, chunks, eng.shard_state(_host_state(stock_invariant))
 
 
 @pytest.fixture(scope="module")
@@ -160,6 +168,39 @@ def test_registration_compiles_nothing(escrow, compiles):
     assert compiles.count(lambda: _run_escrow(escrow)) == 0
     assert {"jit__megastep_escrow", "jit__drain_refresh",
             "jit__drain_strict"} <= set(trace.scope_table())
+
+
+@pytest.mark.parametrize("regime", ["merge", "escrow"])
+def test_steady_call_moves_no_state(request, regime, compiles, tmp_path):
+    eng, ex, chunks, _ = request.getfixturevalue(regime)
+    if regime == "merge":
+        def call(state, esc, warmup=False):
+            return ex.run(state, chunks, warmup=warmup)[0], None
+    else:
+        def call(state, esc, warmup=False):
+            return ex.run_escrow(state, esc, chunks, warmup=warmup)[:2]
+    host = _host_state("strict" if regime == "escrow" else "restock")
+    esc = eng.init_escrow(eng.shard_state(host)) if regime == "escrow" \
+        else None
+    state, esc = call(host, esc, warmup=True)
+    assert ex.last_prepare == Prepared(len(TPCCState._fields), 1)
+
+    assert compiles.count(lambda: call(state, esc)) == 0, \
+        "a call on the previous call's output compiled"
+    assert ex.last_prepare == Prepared(moved_leaves=0, buffer_programs=1)
+    state, esc = compiles.result
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(call(state, esc))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    stats = [dict(e.stats)
+             for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+             for line in plane.lines for e in line.events
+             if e.name == trace.EXEC_PREPARE]
+    assert stats == [{"moved_leaves": 0, "buffer_programs": 1}]
 
 
 def test_scopes_survive_a_cache_entry_without_them(tmp_path):

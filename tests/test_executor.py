@@ -7,8 +7,16 @@
 * donation actually consumes the input buffers (no doubled live state) and
   the compiled module carries input/output aliasing;
 * reduced mixes (no reads / no payments / no deliveries) and ragged tail
-  chunks execute correctly.
+  chunks execute correctly;
+* a call's ring and counters are zeros on the run sharding, a buffer per
+  field, on one device and on four;
+* two calls of one chunk each land bit-exactly where one call of both
+  chunks does, in both regimes.
 """
+
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -18,8 +26,9 @@ import pytest
 from repro.txn.audit import assert_audit
 from repro.txn.engine import (run_closed_loop, run_escrow_loop,
                               run_mixed_loop, single_host_engine)
-from repro.txn.executor import (FusedExecutor, MixChunk, counters_to_stats,
-                                run_fused_loop, stack_chunks)
+from repro.txn.executor import (FusedExecutor, MixChunk, MixCounters,
+                                counters_to_stats, run_fused_loop,
+                                stack_chunks)
 from repro.txn.engine import generate_mix_batches
 from repro.txn.tpcc import TPCCScale, check_consistency, init_state
 
@@ -214,3 +223,106 @@ def test_fused_loop_direct_api(engine):
     assert stats.throughput > 0
     assert all(check_consistency(state).values())
     assert_audit(state)
+
+
+_BUFFERS = r"""
+import jax
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.txn.engine import single_host_engine
+from repro.txn.tpcc import TPCCScale
+from repro.txn.executor import FusedExecutor
+
+n = len(jax.devices())
+scale = TPCCScale(n_warehouses=4, districts=4, customers=8, n_items=64,
+                  order_capacity=128, max_lines=15)
+eng = single_host_engine(scale)
+ex = FusedExecutor(eng, ring_rows=4)
+ring_sh = NamedSharding(eng.mesh, P(None, eng.axis_names))
+count_sh = NamedSharding(eng.mesh, P(eng.axis_names))
+R = 8 * n * scale.max_lines
+ring_dt = ("int32", "int32", "int32", "bool")
+for ring, counters in (ex.init_buffers(8),
+                       (ex.init_ring(8), ex.init_counters())):
+    want = [(x, (4, R), dt, ring_sh) for x, dt in zip(ring, ring_dt)] + [
+        (x, (n,), "int32", count_sh) for x in counters]
+    ptrs = set()
+    for x, shape, dt, sh in want:
+        assert (x.shape, str(x.dtype)) == (shape, dt), (x.shape, x.dtype)
+        assert x._committed and x.sharding == sh, x.sharding
+        assert not np.asarray(x).any()
+        ptrs |= {s.data.unsafe_buffer_pointer() for s in x.addressable_shards}
+    assert len(ptrs) == 13 * n, (len(ptrs), n)
+
+# a call from host state places every table; the next call, on the first
+# call's output, moves none
+from repro.txn.drivers import generate_mix_batches
+from repro.txn.executor import Prepared, stack_chunks
+from repro.txn.tpcc import TPCCState, init_state
+chunks = stack_chunks(*generate_mix_batches(eng, batch_per_shard=8,
+                                            n_batches=2, seed=4), 2)
+state = ex.run(init_state(scale), chunks)[0]
+assert ex.last_prepare == Prepared(len(TPCCState._fields), 1), ex.last_prepare
+ex.run(state, chunks, warmup=False)
+assert ex.last_prepare == Prepared(0, 1), ex.last_prepare
+print("OK", n)
+"""
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_zero_buffers_on_run_sharding(devices):
+    """init_ring / init_counters (and init_buffers, which makes both in one
+    program) give zeros of the ring's and counters' dtypes and shapes,
+    committed to the run sharding, with a buffer of its own for every
+    field on every device; a call on the previous call's output moves no
+    table. Four host devices run in a subprocess so this process keeps
+    one."""
+    if devices == 1:
+        exec(_BUFFERS, {})
+        return
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run([sys.executable, "-c", _BUFFERS], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "OK 4" in out.stdout
+
+
+@pytest.mark.parametrize("stock_invariant", ["restock", "strict"])
+def test_split_calls_bitexact(stock_invariant, engine, escrow_engine):
+    """Two calls of one chunk each equal one call of both chunks: every
+    table (and the escrow shares) bit-exact, and the two calls' counters
+    summing to the single call's. Each call starts from fresh zero buffers
+    and takes the previous call's state as it is."""
+    strict = stock_invariant == "strict"
+    eng = escrow_engine if strict else engine
+    ex = FusedExecutor(eng, ring_rows=4)
+    chunks = stack_chunks(*generate_mix_batches(
+        eng, batch_per_shard=8, n_batches=8, remote_frac=0.3, seed=9), 4)
+
+    def call(state, esc, cs):
+        if strict:
+            state, esc, cnt = ex.run_escrow(state, esc, cs)[:3]
+        else:
+            state, cnt, _ = ex.run(state, cs)
+        return state, esc, jax.device_get(cnt)
+
+    def initial():
+        state = eng.shard_state(init_state(SCALE))
+        return state, eng.init_escrow(state) if strict else None
+
+    s1, e1, whole = call(*initial(), chunks)
+    s2, e2, first = call(*initial(), chunks[:1])
+    s2, e2, second = call(s2, e2, chunks[1:])
+    assert ex.last_prepare.moved_leaves == 0
+
+    assert _tree_equal(s1, s2) == []
+    if strict:
+        assert _tree_equal(e1, e2) == []
+        assert whole.aborts.sum() > 0
+    for f in MixCounters._fields:
+        assert np.array_equal(getattr(whole, f),
+                              getattr(first, f) + getattr(second, f)), f
+    assert whole.neworders.sum() > 0 and whole.deliveries.sum() > 0
