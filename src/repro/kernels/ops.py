@@ -118,8 +118,9 @@ def txn_megastep(avail0, slot, qty, line_valid, key_local, cell_local,
                  local_line, remote_line, ramp_ts, price_row, *,
                  n_keys: int, n_cells: int):
     """One-kernel transaction megastep: gate (Level 1, vectorized jnp) +
-    residual FCFS + committed effects + RAMP stamps with the hot tiles
-    resident in VMEM across all phases (kernels/txn_megastep.py). Bit-exact
+    residual FCFS + committed effects + RAMP stamps with avail resident in
+    VMEM across all phases and the stock slabs streamed over tiles
+    (kernels/txn_megastep.py). Bit-exact
     with the scan path's phase sequence (ref.txn_megastep_ref, property-
     tested in tests/test_megastep_kernel.py).
 

@@ -21,7 +21,8 @@ the strict-stock New-Order hot path over ONE residency of the hot tiles:
             committed per-district rank from an SMEM counter (the
             batched increment-and-get), and the three stock slabs
             (decrement / order count / remote count) accumulate in VMEM
-            instead of three whole-table HBM scatter passes;
+            tiles, streamed to HBM over the grid, instead of three
+            whole-table HBM scatter passes;
   phase 4 — RAMP stamping, vectorized jnp over the whole [B, L] window
             after the kernel (elementwise, so XLA fuses it): the write-set
             timestamp (`ol_ts`) and the line amounts from the pre-gathered
@@ -49,13 +50,20 @@ interpret-mode Pallas pays ~100x per load/store, so off-TPU dispatch
 (ops.txn_megastep) runs the gate + `residual_fcfs` + this, bit-exact with
 the kernel (whose interpret-mode path the tests pin against the oracle).
 
-Memory placement: VMEM holds avail [A] and the three stock slabs [Wl*I],
-each as ``[rows, 128]`` int32 (escrow_admit.to_lanes) — 16 bytes per local
-stock cell, about 16 MB at 10 local warehouses, the most one v5e core's
-default scoped VMEM takes (tests/test_tpu_compile.py: 11 is refused;
-streaming the slabs over a grid lifts the limit). SMEM holds the scalars:
-the per-transaction residual order / verdicts / keys / ranks, the
-per-district counters, and the flat [B * L] line arrays.
+Memory placement: VMEM holds avail [A] whole, as ``[rows, 128]`` int32
+(escrow_admit.to_lanes) — 4 bytes per local stock cell and hot slot — and
+one ``[TILE_ROWS, 128]`` tile of each of the three stock slabs. The slabs
+stream over a grid of tiles: grid step 0 runs phases 2-3 (admission, ranks,
+avail settling); every step zeroes its slab tiles and walks the admitted
+local lines whose cell falls in the tile, in cell order (the caller's sort
+of the lines, ``perm``, and the tile bounds into it), while the pipeline
+writes the previous tile back to HBM. So VMEM holds the slabs' two
+buffered tiles however many warehouses the chip holds, and the kernel's
+ceiling is avail's residency: ``MAX_VMEM_BYTES`` takes 293 local
+warehouses (tests/test_tpu_compile.py compiles the megastep there and
+checks that one warehouse more is refused). SMEM holds the scalars: the
+per-transaction residual order / verdicts / keys / ranks, the
+per-district counters, the flat [B * L] line arrays and the line order.
 """
 
 from __future__ import annotations
@@ -145,67 +153,111 @@ def megastep_effect_products(committed: Array, qty: Array, line_valid: Array,
             amount)
 
 
+TILE_ROWS = 1024          # slab rows a grid step: 131,072 cells, 512 KiB
+MAX_VMEM_BYTES = 120 * 2**20   # of one v5e TensorCore's 128 MiB VMEM
+_SLACK = 4 * 2**20              # Mosaic's own scratch beside the residents
+
+
+def megastep_vmem_bytes(n_avail: int, tile_rows: int = TILE_ROWS) -> int:
+    """VMEM the megastep asks for: avail whole, two buffers of each of the
+    three slab tiles, and slack for the compiler's own scratch."""
+    return (lane_rows(n_avail) + 6 * tile_rows) * LANES * 4 + _SLACK
+
+
+def slab_tiling(n_cells: int) -> tuple[int, int]:
+    """(rows a tile, tiles) of the ``[rows, 128]`` slabs over ``n_cells``
+    local stock cells; a problem smaller than a tile takes one tile."""
+    rows = min(TILE_ROWS, lane_rows(n_cells))
+    return rows, pl.cdiv(lane_rows(n_cells), rows)
+
+
+def local_line_order(cell_local: Array, local_line: Array, n_tiles: int,
+                     tile_cells: int) -> tuple[Array, Array]:
+    """The slab walk's line order: flat line indices sorted by local stock
+    cell, non-local lines last, and the ``[n_tiles + 1]`` bounds of each
+    tile's run in that order."""
+    key = jnp.where(local_line, cell_local,
+                    jnp.iinfo(jnp.int32).max).reshape(-1)
+    perm = jnp.argsort(key).astype(jnp.int32)
+    bounds = jnp.searchsorted(key[perm],
+                              jnp.arange(n_tiles + 1) * tile_cells)
+    return perm, bounds.astype(jnp.int32)
+
+
 def _txn_megastep_body(n_res_ref, res_idx_ref, slot_ref, qty_ref, lv_ref,
-                       fast_ref, key_ref, cell_ref, loc_ref, rem_ref,
-                       avail0_hbm,
+                       fast_ref, key_ref, cell_ref, rem_ref, perm_ref,
+                       bound_ref, avail0_hbm,
                        committed_ref, rank_ref, dcnt_ref,
                        avail_ref, dec_ref, cnt_ref, rcnt_ref):
-    """Phases 2-3 over one VMEM residency of the hot tiles. ``avail_ref``
-    doubles as the running reservation state across both phases;
-    ``dcnt_ref`` (SMEM) doubles as the per-district increment-and-get
-    counter."""
-    copy_smem(fast_ref, committed_ref)
-    pltpu.sync_copy(avail0_hbm, avail_ref)
-
-    def zero_keys(k, carry):
-        dcnt_ref[k] = 0
-        return carry
-
-    jax.lax.fori_loop(0, dcnt_ref.shape[0], zero_keys, 0)
-    for ref in (dec_ref, cnt_ref, rcnt_ref):
-        ref[...] = jnp.zeros(ref.shape, jnp.int32)
+    """Grid step 0: phases 2-3 over the VMEM-resident ``avail_ref``, which
+    doubles as the running reservation state; ``dcnt_ref`` (SMEM) doubles
+    as the per-district increment-and-get counter. Every step: this tile
+    of the three stock slabs."""
     B = committed_ref.shape[0]
     L = slot_ref.shape[0] // B
+    tile = pl.program_id(0)
 
-    # ---- phase 2: residual FCFS (the escrow_admit walk, verbatim) ----------
-    fcfs_walk(n_res_ref, res_idx_ref, slot_ref, qty_ref, lv_ref,
-              committed_ref, avail_ref)
+    @pl.when(tile == 0)
+    def _():
+        copy_smem(fast_ref, committed_ref)
+        pltpu.sync_copy(avail0_hbm, avail_ref)
 
-    # ---- phase 3: committed effects, batch order, avail still resident -----
-    def effect_txn(t, carry):
-        c = committed_ref[t]
-        fast_t = fast_ref[t]
-        # per-district increment-and-get: rank is the count of committed
-        # earlier same-key txns (stored for every txn, like the scan path —
-        # aborted rows' o_ids are computed there too and dropped downstream)
-        key = key_ref[t]
-        kcnt = dcnt_ref[key]
-        rank_ref[t] = kcnt
-        dcnt_ref[key] = kcnt + c
-
-        def line(j, carry):
-            q = qty_ref[j]
-
-            # settle the fast path's reservation in-place: avail leaves the
-            # kernel fully settled (admit_fcfs's contract), no outside
-            # scatter needed
-            @pl.when((lv_ref[j] != 0) & (fast_t != 0))
-            def _():
-                lane_add(avail_ref, slot_ref[j], -q)
-
-            # stock slabs: admitted local lines only
-            @pl.when((c != 0) & (loc_ref[j] != 0))
-            def _():
-                cell = cell_ref[j]
-                lane_add(dec_ref, cell, q)
-                lane_add(cnt_ref, cell, 1)
-                lane_add(rcnt_ref, cell, rem_ref[j])
+        def zero_keys(k, carry):
+            dcnt_ref[k] = 0
             return carry
 
-        jax.lax.fori_loop(t * L, t * L + L, line, 0)
+        jax.lax.fori_loop(0, dcnt_ref.shape[0], zero_keys, 0)
+
+        # ---- phase 2: residual FCFS (the escrow_admit walk, verbatim) ------
+        fcfs_walk(n_res_ref, res_idx_ref, slot_ref, qty_ref, lv_ref,
+                  committed_ref, avail_ref)
+
+        # ---- phase 3: ranks and avail settling, batch order ----------------
+        def effect_txn(t, carry):
+            c = committed_ref[t]
+            fast_t = fast_ref[t]
+            # per-district increment-and-get: rank is the count of committed
+            # earlier same-key txns (stored for every txn, like the scan
+            # path — aborted rows' o_ids are computed there too and dropped
+            # downstream)
+            key = key_ref[t]
+            kcnt = dcnt_ref[key]
+            rank_ref[t] = kcnt
+            dcnt_ref[key] = kcnt + c
+
+            # settle the fast path's reservations in-place: avail leaves
+            # the kernel fully settled (admit_fcfs's contract), no outside
+            # scatter needed
+            @pl.when(fast_t != 0)
+            def _():
+                def line(j, carry):
+                    @pl.when(lv_ref[j] != 0)
+                    def _():
+                        lane_add(avail_ref, slot_ref[j], -qty_ref[j])
+                    return carry
+
+                jax.lax.fori_loop(t * L, t * L + L, line, 0)
+            return carry
+
+        jax.lax.fori_loop(0, B, effect_txn, 0)
+
+    # ---- phase 3, stock slabs: this tile's admitted local lines ------------
+    for ref in (dec_ref, cnt_ref, rcnt_ref):
+        ref[...] = jnp.zeros(ref.shape, jnp.int32)
+    base = tile * (dec_ref.shape[0] * LANES)
+
+    def slab_line(p, carry):
+        j = perm_ref[p]
+
+        @pl.when(committed_ref[j // L] != 0)
+        def _():
+            cell = cell_ref[j] - base
+            lane_add(dec_ref, cell, qty_ref[j])
+            lane_add(cnt_ref, cell, 1)
+            lane_add(rcnt_ref, cell, rem_ref[j])
         return carry
 
-    jax.lax.fori_loop(0, B, effect_txn, 0)
+    jax.lax.fori_loop(bound_ref[tile], bound_ref[tile + 1], slab_line, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("n_keys", "n_cells", "interpret"))
@@ -227,29 +279,43 @@ def txn_megastep_kernel(avail0: Array, slot: Array, qty: Array,
     Returns :class:`MegastepOut` with ``avail`` FULLY settled (fast +
     residual reservations — bit-identical to ``admit_fcfs``'s output).
 
-    Layout: ``avail`` and the three stock slabs ride in ``[rows, 128]``
-    int32 (escrow_admit.to_lanes), so every per-line access is one
-    tile-aligned row; per-transaction and per-line scalars, the verdicts,
-    ranks and district counters live in SMEM.
+    Layout: ``avail`` rides whole in ``[rows, 128]`` int32
+    (escrow_admit.to_lanes), so every per-line access is one tile-aligned
+    row; the stock slabs stream in ``[TILE_ROWS, 128]`` tiles over the
+    grid; per-transaction and per-line scalars, the verdicts, ranks,
+    district counters and the line order live in SMEM.
     """
     B, L = slot.shape
     A = avail0.shape[0]
     lanes = to_lanes(avail0.astype(jnp.int32))
-    slab = jax.ShapeDtypeStruct((lane_rows(n_cells), LANES), jnp.int32)
+    rows, n_tiles = slab_tiling(n_cells)
+    vmem = megastep_vmem_bytes(A, rows)
+    if vmem > MAX_VMEM_BYTES:
+        raise ValueError(
+            f"txn_megastep needs {vmem} bytes of VMEM for {A} avail cells; "
+            f"the budget is {MAX_VMEM_BYTES}")
+    perm, bounds = local_line_order(cell_local, local_line, n_tiles,
+                                    rows * LANES)
+    slab = jax.ShapeDtypeStruct((rows * n_tiles, LANES), jnp.int32)
+    tile = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
     committed, rank, d_count, avail, dec, cnt, rcnt = pl.pallas_call(
         _txn_megastep_body,
-        in_specs=[SMEM] * 10 + [HBM],
-        out_specs=[SMEM] * 3 + [VMEM] * 4,
+        grid=(n_tiles,),
+        in_specs=[SMEM] * 11 + [HBM],
+        out_specs=[SMEM] * 3 + [VMEM] + [tile] * 3,
         out_shape=[jax.ShapeDtypeStruct((B,), jnp.int32),
                    jax.ShapeDtypeStruct((B,), jnp.int32),
                    jax.ShapeDtypeStruct((n_keys,), jnp.int32),
                    jax.ShapeDtypeStruct(lanes.shape, jnp.int32),
                    slab, slab, slab],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
         name="txn_megastep_kernel",
     )(n_res, res_idx, flat_i32(slot), flat_i32(qty), flat_i32(line_valid),
       flat_i32(fast), key_local.astype(jnp.int32), flat_i32(cell_local),
-      flat_i32(local_line), flat_i32(remote_line), lanes)
+      flat_i32(remote_line), perm, bounds, lanes)
     cells = lambda x: x.reshape(-1)[:n_cells]
     # ---- phase 4: RAMP stamps, vectorized over the whole window ------------
     ol_ts = jnp.where(line_valid, ramp_ts[:, None], -1).astype(jnp.int32)

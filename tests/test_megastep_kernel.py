@@ -46,7 +46,8 @@ except ImportError:  # container without hypothesis: deterministic sweep only
 from repro.kernels import ref
 from repro.kernels.escrow_admit import contention_gate, residual_order
 from repro.kernels.ops import escrow_admit, txn_megastep
-from repro.kernels.txn_megastep import (MegastepOut, megastep_effect_products,
+from repro.kernels.txn_megastep import (TILE_ROWS, MegastepOut,
+                                        megastep_effect_products, slab_tiling,
                                         txn_megastep_kernel)
 from repro.txn import tpcc
 from repro.txn.audit import assert_audit
@@ -124,6 +125,17 @@ def test_megastep_equivalence_seeded_sweep():
         else:              # mixed, bigger batch
             args, kw = _mega_problem(seed, B=32, L=8, A=80, n_keys=6,
                                      n_cells=24, hi=60)
+        _assert_mega_equal(args, kw)
+
+
+def test_megastep_slabs_stream_over_tiles():
+    """The stock slabs stream over a grid of ``TILE_ROWS``-row tiles: a
+    problem over 2.5 tiles of local cells takes three, and the kernel
+    still equals the oracle field by field, scarce and plump."""
+    tile_cells = TILE_ROWS * 128
+    for seed, hi in enumerate([12, 300]):
+        args, kw = _mega_problem(seed, n_cells=5 * tile_cells // 2, hi=hi)
+        assert slab_tiling(kw["n_cells"])[1] == 3
         _assert_mega_equal(args, kw)
 
 

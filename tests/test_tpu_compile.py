@@ -5,8 +5,10 @@ pins the kernels' semantics; it never checks tiling, SMEM/VMEM placement or
 the VMEM budget. Here both kernels compile with ``interpret=False`` for a
 described v5e chip (no chip attached) at the chip smoke's spec shapes: one
 chip's ``WAREHOUSES`` local warehouses, and one shard of the four-chip run
-(whose hot set covers all 4 x ``WAREHOUSES`` warehouses). One warehouse
-more than ``WAREHOUSES`` must be refused for VMEM — that is what sets it.
+(whose hot set covers all 4 x ``WAREHOUSES`` warehouses). The megastep
+streams its stock slabs, so its VMEM ceiling is the whole-resident
+availability vector: it compiles at the most local warehouses its VMEM
+budget takes, and one more is refused.
 
 The topology is described inside a fixture, never at import, and every
 chip-compiler test lives in this one file.
@@ -21,7 +23,8 @@ from jax.sharding import SingleDeviceSharding
 
 from chip_smoke import BATCH_PER_SHARD, WAREHOUSES
 from repro.kernels.escrow_admit import escrow_admit_kernel
-from repro.kernels.txn_megastep import txn_megastep_kernel
+from repro.kernels.txn_megastep import (MAX_VMEM_BYTES, megastep_vmem_bytes,
+                                        txn_megastep_kernel)
 from repro.txn.tpcc import TPCCScale, default_hot_items
 
 
@@ -90,10 +93,28 @@ def test_txn_megastep_kernel_compiles_for_v5e(one_chip, n_chips):
     assert mem.output_size_in_bytes >= 16 * sizes["n_cells"]
 
 
+def _vmem_ceiling_warehouses() -> int:
+    """The most local warehouses whose megastep fits ``MAX_VMEM_BYTES``."""
+    def fits(w):
+        scale = TPCCScale.spec_scale(w)
+        n_avail = w * (default_hot_items(scale) + scale.n_items) + 1
+        return megastep_vmem_bytes(n_avail) <= MAX_VMEM_BYTES
+    w = 1
+    while fits(w + 1):
+        w += 1
+    return w
+
+
 def test_txn_megastep_refused_past_warehouse_limit(one_chip):
-    """WAREHOUSES is the most the whole-array megastep holds in VMEM: one
-    local warehouse more is refused by the chip's compiler."""
-    admit, effects, sizes = _spec_args(one_chip, WAREHOUSES + 1, 1)
-    lowered = txn_megastep_kernel.lower(*admit, *effects, **sizes)
+    """The VMEM ceiling is real on both sides: the chip's compiler takes
+    the megastep at the most local warehouses the budget holds (hundreds:
+    the slabs stream, only avail stays whole), and one local warehouse
+    more is refused for VMEM."""
+    top = _vmem_ceiling_warehouses()
+    assert top >= 256
+    admit, effects, sizes = _spec_args(one_chip, top, 1)
+    _assert_kernel_compiled(
+        txn_megastep_kernel.lower(*admit, *effects, **sizes))
+    admit, effects, sizes = _spec_args(one_chip, top + 1, 1)
     with pytest.raises(Exception, match="(?i)vmem"):
-        lowered.compile()
+        txn_megastep_kernel.lower(*admit, *effects, **sizes).compile()
